@@ -35,7 +35,7 @@ import sys
 import tempfile
 import time
 
-from .common import REPO_ROOT, emit, emit_json
+from .common import REPO_ROOT, emit, emit_json, refuse_on_tpu
 
 T = 16
 BATCH = 4
@@ -144,14 +144,7 @@ def sweep() -> list:
 
 def run(fast: bool = True) -> None:
     del fast                        # 4 short subprocesses either way
-    from repro.serving import aot_supported
-
-    if not aot_supported():
-        # memory-tier-only jax: the warm modes would silently re-measure a
-        # cold start; emit the fact instead of a misleading comparison
-        emit("cold_start_skipped", "0", "jax lacks serialize_executable")
-        emit_json("cold_start", {"aot_supported": False, "rows": []})
-        return
+    refuse_on_tpu("benchmarks.cold_start")
     rows = sweep()
     for row in rows:
         emit(f"cold_start_{row['mode']}", f"{row['ttfr_ms'] * 1e3:.1f}",
@@ -160,7 +153,6 @@ def run(fast: bool = True) -> None:
              f";disk_hits={row['disk_hits']}")
     by_mode = {r["mode"]: r for r in rows}
     emit_json("cold_start", {
-        "aot_supported": True,
         "dim": DIM, "T": T, "batch": BATCH, "sweeps": SWEEPS,
         "requests": REQUESTS,
         "cold_ttfr_ms": by_mode["cold"]["ttfr_ms"],

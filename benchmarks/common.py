@@ -48,6 +48,23 @@ def synthetic_dataset(m: int, n: int, seed: int = 0,
     return x.astype(np.float32)
 
 
+def refuse_on_tpu(name: str) -> None:
+    """Refuse a sweep that starts device-using child processes on a TPU.
+
+    A chip belongs to one process at a time.  Once this process has
+    touched JAX on a TPU it holds the chip, and a child that needs a
+    device then fails or hangs.  The sweeps that call this are CPU-regime
+    measurements (forced host-device counts, fresh-process replicas); they
+    run under ``JAX_PLATFORMS=cpu`` and are not chip benchmarks.
+    """
+    if jax.default_backend() == "tpu":
+        raise RuntimeError(
+            f"{name} starts child processes that need a device, but this "
+            "process already holds the TPU and a chip serves one process "
+            "at a time; it is a CPU-regime sweep: run it with "
+            "JAX_PLATFORMS=cpu")
+
+
 def time_call(fn: Callable, *args, reps: int = 3, warmup: int = 1) -> float:
     """Median wall-time of a jitted call in microseconds."""
     for _ in range(warmup):

@@ -45,7 +45,7 @@ from repro.launch.serve_pca import mixed_traffic
 from repro.serving import (BucketPolicy, LocalExecutor, MeshExecutor,
                            PCAServer, host_mesh, threshold_router)
 
-from .common import REPO_ROOT, emit, emit_json
+from .common import REPO_ROOT, emit, emit_json, refuse_on_tpu
 
 MIXED_DIMS = (10, 14, 18, 24, 29, 31, 37, 46)
 
@@ -217,6 +217,7 @@ def async_sweep_subprocess() -> list:
 def run(fast: bool = True) -> None:
     import jax
 
+    refuse_on_tpu("benchmarks.serve_throughput")
     n_req = 32 if fast else 128
     mats = mixed_traffic(n_req, "eigh", MIXED_DIMS)
     grid = [(16, 1, "tile"),            # serve-one-at-a-time baseline

@@ -13,8 +13,9 @@ import jax.numpy as jnp
 
 from repro.core import PCAConfig, fit_distributed
 from repro.core.memory_model import ARTIX7, VIRTEX_US, pca_seconds
+from repro.parallel.sharding import make_mesh
 
-mesh = jax.make_mesh((len(jax.devices()),), ("data",))
+mesh = make_mesh((len(jax.devices()),), ("data",))
 rng = np.random.default_rng(1)
 X = (rng.standard_normal((4096, 8)) @ rng.standard_normal((8, 64))
      ).astype(np.float32)

@@ -14,13 +14,14 @@ dataset size -- the paper's scale-invariance claim.  Three paths:
 """
 from __future__ import annotations
 
+import functools
 from typing import Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.parallel.sharding import shard_map_compat
+from .precision import matmul_precision
 
 
 def standardize(X, eps: float = 1e-8) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
@@ -67,7 +68,8 @@ def blocked_covariance(
         from repro.kernels import ops as kops
         return kops.covariance(X, block_m=block_m, precision=precision,
                                normalize=normalize, backend=backend)
-    mm = matmul_fn or jnp.matmul
+    mm = matmul_fn or functools.partial(
+        jnp.matmul, precision=matmul_precision(precision))
     m, n = X.shape
     pad = (-m) % block_m
     if pad:
@@ -105,11 +107,11 @@ def distributed_covariance(
         c = blocked_covariance(x, block_m=block_m, matmul_fn=matmul_fn)
         return jax.lax.psum(c, axis_name=data_axis)
 
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=P(data_axis, None),
         out_specs=P(),
-        check_replication=True,
+        check_vma=True,
     )
     return fn(X)

@@ -11,6 +11,7 @@ tile size (Pallas block edge / streaming block), S the parallelism index
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Callable, NamedTuple, Optional
 
 import jax
@@ -18,6 +19,7 @@ import jax.numpy as jnp
 
 from .covariance import blocked_covariance, covariance, distributed_covariance, standardize
 from .jacobi import DEFAULT_SWEEPS, EighResult, jacobi_eigh
+from .precision import matmul_precision
 from .schedule import SweepSchedule
 
 
@@ -116,7 +118,8 @@ def fit(X, config: PCAConfig = PCAConfig()) -> PCAResult:
 def transform(X, result: PCAResult, k: int, config: PCAConfig = PCAConfig()):
     """Project onto the top-k subspace: O = X_std V_k (paper eq. 5)."""
     Xs = (jnp.asarray(X) - result.mean) / result.scale
-    mm = config.matmul_fn() or jnp.matmul
+    mm = config.matmul_fn() or functools.partial(
+        jnp.matmul, precision=matmul_precision(config.precision))
     return mm(Xs, result.components[:, :k])
 
 

@@ -34,6 +34,7 @@ import tempfile
 from typing import Dict
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
 PRECISIONS = ("fp32", "bf16_fp32acc", "fp64")
@@ -64,6 +65,21 @@ def operand_dtype(precision: str):
     if precision == "fp64":
         return jnp.float64
     return jnp.float32
+
+
+def matmul_precision(precision: str):
+    """The XLA dot precision of the policy's plain (non-kernel) matmuls.
+
+    A TPU runs an fp32 dot at DEFAULT precision as one bf16 pass: on a
+    v5e that put a 176x44 SVD's singular values 1.5e-4 from float64,
+    over the fp32 budget.  ``fp32`` (and ``fp64``) therefore ask for
+    HIGHEST; ``bf16_fp32acc`` keeps DEFAULT -- it is the reduced-precision
+    lane.  On a CPU the setting changes nothing.
+    """
+    validate(precision)
+    if precision == "bf16_fp32acc":
+        return None
+    return jax.lax.Precision.HIGHEST
 
 
 def acc_dtype(precision: str):
@@ -126,7 +142,9 @@ def run_fp64_oracle(X: np.ndarray, op: str, sweeps: int = 50,
         os.path.abspath(__file__))))
     env = dict(os.environ)
     env["JAX_ENABLE_X64"] = "1"
-    env["JAX_PLATFORMS"] = env.get("JAX_PLATFORMS", "cpu")
+    # the oracle is a host computation: pinned to the CPU, it never
+    # competes with the parent for an accelerator the parent holds
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = src_root + os.pathsep + env.get("PYTHONPATH", "")
     with tempfile.TemporaryDirectory() as td:
         inp = os.path.join(td, "in.npz")

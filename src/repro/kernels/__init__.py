@@ -9,7 +9,7 @@
 Import ``repro.kernels.ops`` for the jit'd padded wrappers (each dispatches
 through the ``repro.backends`` registry to a ``pallas`` / ``interpret`` /
 ``ref`` implementation) and ``repro.kernels.ref`` for the pure-jnp oracles.
-``repro.kernels.compat`` pins the version-portable Pallas TPU API surface;
+``repro.kernels.compat`` is the one import point of the Pallas TPU API;
 kernel modules must import ``pl`` / memory spaces / compiler params from it
 rather than from ``jax.experimental.pallas.tpu`` directly.
 """

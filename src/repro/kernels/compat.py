@@ -1,37 +1,16 @@
-"""Version-compat layer for the Pallas TPU API surface.
+"""The single import point for the Pallas TPU API surface.
 
-jax moved the TPU lowering parameters around between releases:
-
-* jax <= 0.4.x spells the dataclass ``pltpu.TPUCompilerParams`` and accepts
-  ``dimension_semantics`` as a constructor field;
-* newer jax renames it ``pltpu.CompilerParams`` (same fields).
-
-A jax exposing neither spelling is explicitly unsupported: the resolution
-below fails loudly at the first kernel call instead of guessing at an
-untestable legacy kwarg.
-
-Every kernel module imports ``pl``/``pltpu`` and builds its
-``compiler_params`` through this module -- it is the ONLY place in the repo
-that imports ``jax.experimental.pallas.tpu`` directly, so a future API move
-is a one-file fix.  ``PALLAS_API_VARIANT`` names the resolved spelling so CI
-logs make version drift visible (see ``scripts/ci.sh``).
+Every kernel module imports ``pl`` and builds its ``compiler_params`` and
+scratch memory spaces through this module -- it is the ONLY place in the
+repo that imports ``jax.experimental.pallas.tpu`` directly, so a future API
+move is a one-file fix.
 """
 from __future__ import annotations
 
 from typing import Any, Dict, Optional, Sequence
 
 from jax.experimental import pallas as pl  # noqa: F401  (re-exported)
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401  (re-exported)
-
-if hasattr(pltpu, "CompilerParams"):          # jax >= 0.5 spelling
-    _COMPILER_PARAMS_CLS = pltpu.CompilerParams
-    PALLAS_API_VARIANT = "pltpu.CompilerParams"
-elif hasattr(pltpu, "TPUCompilerParams"):     # jax 0.4.x spelling
-    _COMPILER_PARAMS_CLS = pltpu.TPUCompilerParams
-    PALLAS_API_VARIANT = "pltpu.TPUCompilerParams"
-else:
-    _COMPILER_PARAMS_CLS = None
-    PALLAS_API_VARIANT = "unsupported (no CompilerParams spelling found)"
+from jax.experimental.pallas import tpu as pltpu
 
 # scratch memory spaces, re-exported so kernels never touch pltpu directly
 VMEM = pltpu.VMEM
@@ -44,29 +23,20 @@ def compiler_params(
 ) -> Dict[str, Any]:
     """kwargs for ``pl.pallas_call`` selecting the TPU compiler parameters.
 
-    Returns ``{"compiler_params": <resolved object>}`` (or ``{}`` when
-    nothing was requested) so call sites splat it:
+    Returns ``{"compiler_params": pltpu.CompilerParams(...)}`` (or ``{}``
+    when nothing was requested) so call sites splat it:
 
         pl.pallas_call(kernel, ..., **compat.compiler_params(
             dimension_semantics=("parallel", "arbitrary")))
-
-    ``dimension_semantics`` entries are the portable spellings
-    ``"parallel"`` / ``"arbitrary"``.
     """
     if dimension_semantics is None and not kwargs:
         return {}
-    if _COMPILER_PARAMS_CLS is None:
-        import jax
-        raise RuntimeError(
-            f"jax {jax.__version__} exposes neither pltpu.CompilerParams "
-            "nor pltpu.TPUCompilerParams; add its spelling to "
-            "repro.kernels.compat (the single Pallas-TPU import point)")
     dims = tuple(dimension_semantics) if dimension_semantics else None
-    return {"compiler_params": _COMPILER_PARAMS_CLS(
+    return {"compiler_params": pltpu.CompilerParams(
         dimension_semantics=dims, **kwargs)}
 
 
 def describe() -> str:
-    """One-line API resolution summary for CI logs."""
+    """One-line API summary for CI logs."""
     import jax
-    return (f"jax {jax.__version__}: compiler params via {PALLAS_API_VARIANT}")
+    return f"jax {jax.__version__}: compiler params via pltpu.CompilerParams"

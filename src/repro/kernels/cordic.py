@@ -21,6 +21,7 @@ from . import compat
 from .compat import pl
 
 _ONE_F = float(1 << _FRAC_BITS)
+_LANES, _SUBLANES = 128, 8
 
 
 def _cordic_kernel(apq_ref, app_ref, aqq_ref, th_ref, c_ref, s_ref, *,
@@ -74,25 +75,36 @@ def cordic_rotation_params(
     iters: int = CORDIC_ITERS,
     interpret: bool = False,
 ):
-    """(theta, cos, sin) for each pivot; 1-D inputs of any common length."""
+    """(theta, cos, sin) for each pivot; 1-D inputs of any common length.
+
+    The pivots are laid out lane-dense as a (rows, 128) slab, tiled in
+    row panels of ``block`` pivots (rounded up to whole (8, 128) vreg
+    tiles; a slab smaller than one panel is a single full-array block).
+    A 1-D block is refused by the TPU compiler as soon as XLA picks a
+    wider 1-D tiling for the operand than the block (length 448 pads to
+    512 and gets ``T(512)`` against a 256 block); 2-D (8, 128) tiling is
+    what both sides agree on at every length.
+    """
     (k,) = apq.shape
-    pad = (-k) % block
-    if pad:
-        apq = jnp.pad(apq, (0, pad))
-        app = jnp.pad(app, (0, pad), constant_values=1.0)
-        aqq = jnp.pad(aqq, (0, pad))
-    n = apq.shape[0]
-    grid = (n // block,)
-    spec = pl.BlockSpec((block,), lambda i: (i,))
+    rows = max(1, -(-k // _LANES))
+    panel = max(_SUBLANES, -(-block // (_LANES * _SUBLANES)) * _SUBLANES)
+    panel = min(panel, rows)
+    rows = -(-rows // panel) * panel
+    pad = rows * _LANES - k
+
+    def slab(x, fill=0.0):
+        x = jnp.pad(x.astype(jnp.float32), (0, pad), constant_values=fill)
+        return x.reshape(rows, _LANES)
+
+    spec = pl.BlockSpec((panel, _LANES), lambda i: (i, 0))
     th, c, s = pl.pallas_call(
         functools.partial(_cordic_kernel, iters=iters),
-        grid=grid,
+        grid=(rows // panel,),
         in_specs=[spec, spec, spec],
         out_specs=[spec, spec, spec],
-        out_shape=[jax.ShapeDtypeStruct((n,), jnp.float32)] * 3,
+        out_shape=[jax.ShapeDtypeStruct((rows, _LANES), jnp.float32)] * 3,
         interpret=interpret,
         name="cordic",
         **compat.compiler_params(dimension_semantics=("parallel",)),
-    )(apq.astype(jnp.float32), app.astype(jnp.float32),
-      aqq.astype(jnp.float32))
-    return th[:k], c[:k], s[:k]
+    )(slab(apq), slab(app, 1.0), slab(aqq))
+    return (th.reshape(-1)[:k], c.reshape(-1)[:k], s.reshape(-1)[:k])

@@ -36,7 +36,7 @@ from repro.launch.mesh import make_production_mesh
 from repro.models import transformer as tfm
 from repro.optim import adamw
 from repro.optim import compression as comp
-from repro.parallel.sharding import REPLICATED, shard_map_compat, use_mesh
+from repro.parallel.sharding import REPLICATED
 
 
 def build(cfg, mesh, seq, global_batch, mode: str, rank: int):
@@ -79,9 +79,9 @@ def build(cfg, mesh, seq, global_batch, mode: str, rank: int):
     comp_spec = jax.tree.map(lambda l: P("pod", *([None] * (l.ndim - 1))),
                              ab_comp)
 
-    fn = shard_map_compat(device_local, mesh=mesh,
-                          in_specs=(params_spec, tok_spec, comp_spec),
-                          out_specs=(params_spec, comp_spec))
+    fn = jax.shard_map(device_local, mesh=mesh,
+                       in_specs=(params_spec, tok_spec, comp_spec),
+                       out_specs=(params_spec, comp_spec), check_vma=False)
     in_sh = jax.tree.map(lambda s: NamedSharding(mesh, s),
                          (params_spec, tok_spec, comp_spec),
                          is_leaf=lambda x: isinstance(x, P))
@@ -106,7 +106,7 @@ def main():
     for mode in ("baseline", "compressed"):
         fn, in_sh, ab = build(cfg, mesh, args.seq, args.batch, mode,
                               args.rank)
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             compiled = jax.jit(fn, in_shardings=in_sh).lower(*ab).compile()
         colls = collective_bytes(compiled.as_text())
         rec[mode] = {"collectives": colls,

@@ -73,19 +73,21 @@ import argparse
 import dataclasses
 import json
 import sys
+import tempfile
 import warnings
 
 import numpy as np
 
 from repro.core import PCAConfig
 from repro.core.memory_model import VIRTEX_US
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import Observability, device_profile, validate_trace
 from repro.serving import (ADMISSION_MODES, ARRIVALS, BucketPolicy,
                            CacheSpec, ControllerSpec, CostModel,
                            ExecutionSpec, ObsSpec, PCAServer, POLICIES,
                            SCHEDULERS, SchedulingSpec, ServerSpec,
                            SpecConflictError, TenantSpec, TrafficFrontend,
-                           TrafficProfile, VirtualClock, aot_supported,
+                           TrafficProfile, VirtualClock,
                            autotune, build_server, generate, materialize,
                            merge, mesh_executor, parse_tenants, plan_grid,
                            profile_of, resolve_spec, server_for_plan)
@@ -253,39 +255,35 @@ def selftest() -> int:
     # compiles) and serve the identical burst *bit-for-bit* equal to the
     # cold-JIT replica -- the AOT serialize/deserialize round trip must
     # never touch the math
-    cold_info = {"skipped": True}
-    if aot_supported():
-        import tempfile
-        seed_profile = TrafficProfile.from_shapes(
-            [("eigh", m.shape, 1) for m in mats]
-            + [("svd", a.shape, 1) for a in svd_in])
-        with tempfile.TemporaryDirectory() as cdir:
-            cache_spec = dataclasses.replace(
-                base_spec, cache=CacheSpec(cache_dir=cdir))
-            seeder = PCAServer.from_spec(cache_spec)
-            seeded = seeder.warmup(seed_profile)
-            assert seeded["compile"] == seeded["executables"], seeded
-            stores = seeder.cache_summary()["disk"]["stores"]
-            assert stores == seeded["executables"], seeder.cache_summary()
-            warm = PCAServer.from_spec(cache_spec)
-            warmed = warm.warmup(seed_profile)
-            assert warmed["disk"] == warmed["executables"], warmed
-            assert warmed["compile"] == 0, warmed
-            for op, traffic in (("eigh", mats), ("svd", svd_in)):
-                got = warm.solve_many(traffic, op=op)
-                want = srv.solve_many(traffic, op=op)
-                for g, w in zip(got, want):
-                    for field in (f.name for f in dataclasses.fields(g)):
-                        np.testing.assert_array_equal(
-                            np.asarray(getattr(g, field)),
-                            np.asarray(getattr(w, field)),
-                            err_msg=f"warm-vs-cold {op}.{field}")
-            warm_summary = warm.stats.summary()
-            assert warm_summary["cache_hit_rate"] == 1.0, warm_summary
-            cold_info = {"skipped": False,
-                         "executables": warmed["executables"],
-                         "disk_hits": warmed["disk"],
-                         "warmup_s": round(warmed["seconds"], 4)}
+    seed_profile = TrafficProfile.from_shapes(
+        [("eigh", m.shape, 1) for m in mats]
+        + [("svd", a.shape, 1) for a in svd_in])
+    with tempfile.TemporaryDirectory() as cdir:
+        cache_spec = dataclasses.replace(
+            base_spec, cache=CacheSpec(cache_dir=cdir))
+        seeder = PCAServer.from_spec(cache_spec)
+        seeded = seeder.warmup(seed_profile)
+        assert seeded["compile"] == seeded["executables"], seeded
+        stores = seeder.cache_summary()["disk"]["stores"]
+        assert stores == seeded["executables"], seeder.cache_summary()
+        warm = PCAServer.from_spec(cache_spec)
+        warmed = warm.warmup(seed_profile)
+        assert warmed["disk"] == warmed["executables"], warmed
+        assert warmed["compile"] == 0, warmed
+        for op, traffic in (("eigh", mats), ("svd", svd_in)):
+            got = warm.solve_many(traffic, op=op)
+            want = srv.solve_many(traffic, op=op)
+            for g, w in zip(got, want):
+                for field in (f.name for f in dataclasses.fields(g)):
+                    np.testing.assert_array_equal(
+                        np.asarray(getattr(g, field)),
+                        np.asarray(getattr(w, field)),
+                        err_msg=f"warm-vs-cold {op}.{field}")
+        warm_summary = warm.stats.summary()
+        assert warm_summary["cache_hit_rate"] == 1.0, warm_summary
+        cold_info = {"executables": warmed["executables"],
+                     "disk_hits": warmed["disk"],
+                     "warmup_s": round(warmed["seconds"], 4)}
 
     # frontend leg: the open-loop path must be *reproducible* -- a seeded
     # arrival stream through admission + WFQ under a virtual clock gives
@@ -500,7 +498,7 @@ def main(argv=None) -> int:
                     help="shard each flush's batch axis across a device "
                          "mesh: 'none' (single device, default), 'auto' "
                          "(every visible device), or an integer N (first N "
-                         "devices; clamps to what is visible).  Use "
+                         "devices; an error if fewer are visible).  Use "
                          "XLA_FLAGS=--xla_force_host_platform_device_count=8 "
                          "to carve host devices out of one CPU.")
     ap.add_argument("--inflight", type=int, default=1,
@@ -611,6 +609,7 @@ def main(argv=None) -> int:
     ap.add_argument("--selftest", action="store_true",
                     help="run the 2-second smoke and exit")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.selftest:
         return selftest()

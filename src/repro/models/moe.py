@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.parallel.sharding import Px, shard_map_compat
+from repro.parallel.sharding import Px
 from .config import ModelConfig
 from .layers import _normal
 
@@ -191,12 +191,12 @@ def apply_moe(p, x, cfg: ModelConfig, rules, mlp_res=None, mlp_shared=None
             mlp_args += [mlp_p["wi"], mlp_p.get("wg"), mlp_p["wo"]]
             mlp_specs += [P(fsdp_ax, ep_axis), P(fsdp_ax, ep_axis),
                           P(ep_axis, fsdp_ax)]
-    y, aux = shard_map_compat(
+    y, aux = jax.shard_map(
         local, mesh=mesh,
         in_specs=(tok_spec,
                   P(ep_axis, fsdp_ax, None), P(ep_axis, fsdp_ax, None),
                   P(ep_axis, None, fsdp_ax), *mlp_specs),
-        out_specs=(tok_spec, P()),
+        out_specs=(tok_spec, P()), check_vma=False,
     )(xf, p["wi"], p["wg"], p["wo"], *mlp_args)
     return y.reshape(b, s, d).astype(x.dtype), aux
 

@@ -350,22 +350,16 @@ def device_profile(logdir: Optional[str] = None):
 
     With a log directory, starts a JAX profiler trace so the device-side
     picture (XLA op timings, TensorBoard/Perfetto-loadable) lands next to
-    the host-side span trace; a ``None``/empty logdir -- or a jax build
-    without profiler support -- is a no-op, so callers can wrap
-    unconditionally.
+    the host-side span trace; a ``None``/empty logdir is a no-op, so
+    callers can wrap unconditionally.  A profiler that cannot start
+    raises: a run that asked for a device trace must not finish without
+    one.
     """
     if not logdir:
         yield
         return
     import jax
-    try:
-        jax.profiler.start_trace(str(logdir))
-    except Exception as e:          # pragma: no cover - backend-dependent
-        import warnings
-        warnings.warn(f"jax.profiler unavailable ({e}); device profile "
-                      f"skipped")
-        yield
-        return
+    jax.profiler.start_trace(str(logdir))
     try:
         yield
     finally:

@@ -28,7 +28,7 @@ from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding
+from jax.sharding import AxisType, Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 class Px:
@@ -132,27 +132,16 @@ class Rules:
 REPLICATED = Rules(mesh_axes=(), fsdp=False, tensor=False)
 
 
-def shard_map_compat(f, mesh, in_specs, out_specs,
-                     check_replication: bool = False):
-    """Version-portable ``shard_map`` (the mesh-API analogue of
-    ``repro.kernels.compat``): newer jax spells it ``jax.shard_map`` with
-    ``check_vma``; 0.4.x has ``jax.experimental.shard_map`` with
-    ``check_rep``."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs,
-                             check_vma=check_replication)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_replication)
+def make_mesh(shape, axes, devices=None) -> Mesh:
+    """``jax.make_mesh`` with every axis ``Auto``.
 
-
-def use_mesh(mesh: Mesh):
-    """Context manager activating ``mesh``: ``jax.set_mesh`` on newer jax;
-    on 0.4.x a ``Mesh`` is itself the context manager."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+    ``jax.make_mesh`` builds ``Explicit`` axes by default, and
+    ``with_sharding_constraint`` (``Rules.shard``) refuses those.  Every
+    mesh in this repository is built here, so the rule table's
+    constraints hold on all of them."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def rules_for_mesh(mesh: Mesh, **kw) -> Rules:

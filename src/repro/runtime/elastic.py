@@ -14,6 +14,7 @@ from typing import Optional, Tuple
 import jax
 
 from repro.checkpoint import checkpointer
+from repro.parallel.sharding import make_mesh
 
 
 def pick_mesh(model_parallel: int, devices=None, global_batch=None):
@@ -35,8 +36,8 @@ def pick_mesh(model_parallel: int, devices=None, global_batch=None):
         dp = min(dp, global_batch)
         while dp > 1 and global_batch % dp:
             dp -= 1
-    return jax.make_mesh((dp, tp), ("data", "model"),
-                         devices=devices[: dp * tp])
+    return make_mesh((dp, tp), ("data", "model"),
+                     devices=devices[: dp * tp])
 
 
 def resume_or_init(ckpt_dir, state_like, shardings, init_fn,

@@ -36,7 +36,7 @@ from .autotune import (AutotuneResult, CostModel, ServingPlan,
 from .batching import (BucketPolicy, POLICIES, pad_to_bucket, padding_waste,
                        stack_requests)
 from .cache import (DiskCache, ExecutableCache, LRUCache, SolverKey,
-                    aot_supported, content_hash, environment_fingerprint)
+                    content_hash, environment_fingerprint)
 from .controller import ServingController
 from .engine import (BackendRouter, OPS, PCAServer, ServedEigh, ServedPCA,
                      ServedSVD, Ticket, threshold_router)
@@ -72,7 +72,7 @@ __all__ = [
     "ServedEigh", "ServedPCA", "ServedSVD", "ServerSpec",
     "ServingController", "ServingPlan", "ServingStats", "SolverKey",
     "SpecConflictError", "Ticket", "TrafficProfile", "TRACE_KINDS",
-    "aot_supported", "autotune", "bandit_search", "build_server",
+    "autotune", "bandit_search", "build_server",
     "build_solver_fn", "content_hash", "environment_fingerprint",
     "host_mesh", "jacobi_eigh_batched", "jacobi_svd_batched",
     "mesh_executor", "pad_to_bucket", "padding_waste", "pca_fit_batched",

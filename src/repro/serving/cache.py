@@ -32,8 +32,8 @@ subset -- two configs that differ only in scheduling facts (T, S) now share
 one executable, which is exactly why a plan hot-swap that preserves
 bucketing keeps its whole cache.  The disk tier hashes ``SolverKey``
 together with (op, bucket, batch, executor token, jax version, device
-backend), so an entry is invalidated -- cleanly, by never being looked up
--- the moment any of those change.
+backend, device kind), so an entry is invalidated -- cleanly, by never
+being looked up -- the moment any of those change.
 """
 from __future__ import annotations
 
@@ -51,8 +51,9 @@ import jax
 # bump when the on-disk record layout changes; part of the content hash so
 # old-format entries are simply never looked up again
 # (2: SolverKey grew precision + fused -- pre-mixed-precision executables
-# must never serve a precision-keyed request)
-CACHE_FORMAT = 2
+# must never serve a precision-keyed request; 3: the record header is the
+# whole environment fingerprint, device kind included)
+CACHE_FORMAT = 3
 
 # default in-memory cap: generous for steady traffic (a few ops x a few
 # buckets x a few batches), small enough that a plan-churning server stays
@@ -62,26 +63,14 @@ DEFAULT_MAX_ENTRIES = 256
 DEFAULT_MAX_DISK_BYTES = 1 << 30    # 1 GiB of serialized executables
 
 
-def aot_supported() -> bool:
-    """Can this jax serialize compiled executables?
-
-    The pickled-PJRT path (``jax.experimental.serialize_executable``) is
-    the only one that skips XLA at load time (``jax.export`` round-trips
-    StableHLO, which still compiles on load -- no cold-start win).  Absent
-    support degrades to memory-tier-only serving, never an error.
-    """
-    try:
-        from jax.experimental import serialize_executable  # noqa: F401
-        return True
-    except ImportError:         # pragma: no cover - depends on jax build
-        return False
-
-
-def environment_fingerprint() -> Tuple[str, str]:
-    """(jax version, device backend) -- the facts that invalidate every
-    serialized executable at once when they drift (an XLA binary compiled
-    by one jax for one backend must never load into another)."""
-    return (jax.__version__, jax.default_backend())
+def environment_fingerprint() -> Tuple[str, str, str]:
+    """(jax version, device backend, device kind) -- the facts that
+    invalidate every serialized executable at once when they drift.  An
+    XLA binary compiled by one jax for one backend must never load into
+    another, and one compiled for one TPU generation (``device_kind``,
+    e.g. "TPU v5 lite") must never be offered to another."""
+    return (jax.__version__, jax.default_backend(),
+            jax.devices()[0].device_kind)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -129,9 +118,9 @@ def content_hash(op: str, bucket: Tuple[int, ...], batch: int,
     Everything that changes the compiled binary is in the digest: the op,
     the concrete shapes (bucket, batch), the solver numerics, the
     executor placement token (mesh axes + device ids for a mesh), the
-    jax version, the device backend, and the record format.  A mismatch
-    in any of them lands on a different file -- stale entries are never
-    loaded, only eventually evicted by the size cap.
+    jax version, the device backend and kind, and the record format.  A
+    mismatch in any of them lands on a different file -- stale entries are
+    never loaded, only eventually evicted by the size cap.
     """
     material = repr((CACHE_FORMAT, op, tuple(bucket), int(batch),
                      dataclasses.astuple(solver), exec_token,
@@ -194,7 +183,7 @@ class DiskCache:
     """Content-addressed directory of serialized AOT executables.
 
     One file per executable: ``<sha256>.jexec`` holding a pickled record
-    ``{"format", "jax", "backend", "payload", "in_tree", "out_tree"}``
+    ``{"format", "env", "payload", "in_tree", "out_tree"}``
     (the ``serialize_executable.serialize`` triple plus the header that
     lets a loader reject an entry copied across environments even when the
     file name happens to match).  All failure modes degrade to a miss:
@@ -237,12 +226,10 @@ class DiskCache:
         try:
             record = pickle.loads(blob)
             if (record["format"] != CACHE_FORMAT
-                    or (record["jax"], record["backend"])
-                    != environment_fingerprint()):
+                    or tuple(record["env"]) != environment_fingerprint()):
                 raise ValueError(
-                    f"cache entry from jax {record.get('jax')}/"
-                    f"{record.get('backend')}, this process is "
-                    f"{environment_fingerprint()}")
+                    f"cache entry from {record.get('env')}, this process "
+                    f"is {environment_fingerprint()}")
             from jax.experimental import serialize_executable
             fn = serialize_executable.deserialize_and_load(
                 record["payload"], record["in_tree"], record["out_tree"])
@@ -271,10 +258,9 @@ class DiskCache:
             from jax.experimental import serialize_executable
             payload, in_tree, out_tree = serialize_executable.serialize(
                 compiled)
-            jax_version, backend = environment_fingerprint()
             blob = pickle.dumps({
-                "format": CACHE_FORMAT, "jax": jax_version,
-                "backend": backend, "payload": payload,
+                "format": CACHE_FORMAT, "env": environment_fingerprint(),
+                "payload": payload,
                 "in_tree": in_tree, "out_tree": out_tree,
             })
         except Exception:
@@ -354,7 +340,7 @@ class ExecutableCache:
                  max_disk_bytes: int = DEFAULT_MAX_DISK_BYTES):
         self.mem = LRUCache(max_entries=max_entries)
         self.disk: Optional[DiskCache] = None
-        if cache_dir is not None and aot_supported():
+        if cache_dir is not None:
             self.disk = DiskCache(cache_dir, max_bytes=max_disk_bytes)
 
     # -- mapping surface (the old dict's contract) --------------------------

@@ -169,11 +169,13 @@ class MeshExecutor(LocalExecutor):
       devices: devices for the default mesh (ignored when ``mesh`` given).
       data_axis: mesh axis the batch dim shards over.
 
-    Numerics are placement-invariant: each problem in the batch lives
-    entirely on one shard (the batch dim is the only sharded dim), so a
-    sharded flush is bit-for-bit the same math as the single-device flush
-    on every problem -- parity is tested per op in
-    ``tests/test_sharded_serving.py``.
+    Each problem in the batch lives entirely on one shard (the batch dim
+    is the only sharded dim), so a sharded flush runs the same math as the
+    single-device flush on every problem.  It is a separate compile,
+    though, and XLA may fuse and round it differently: results agree to
+    rounding (relative 1e-5), not bit for bit, and the solver's canonical
+    eigenvector sign keeps columns from flipping -- parity is tested per
+    op in ``tests/test_sharded_serving.py``.
     """
 
     def __init__(self, mesh: Optional[Mesh] = None,
@@ -229,12 +231,15 @@ class MeshExecutor(LocalExecutor):
 def host_mesh(n_devices: Optional[int] = None,
               data_axis: str = "data") -> Mesh:
     """A 1-D data mesh over the first ``n_devices`` visible devices
-    (None/0 = all).  Degrades gracefully: asking for more devices than
-    visible clamps rather than raising, so the same launch line works on a
-    laptop (1 device) and under
-    ``XLA_FLAGS=--xla_force_host_platform_device_count=8``."""
+    (None/0 = all).  Asking for more devices than are visible raises:
+    a run that asked for N devices and got fewer would report numbers
+    for a mesh it never ran on."""
     devs = jax.devices()
-    n = len(devs) if not n_devices else min(n_devices, len(devs))
+    n = n_devices or len(devs)
+    if n > len(devs):
+        raise ValueError(
+            f"a {n}-device mesh was requested but only {len(devs)} "
+            f"{devs[0].platform} device(s) are visible")
     return Mesh(np.asarray(devs[:n]), (data_axis,))
 
 
@@ -243,7 +248,7 @@ def mesh_executor(spec) -> LocalExecutor:
 
     ``None``/``"none"``/``"1"`` -> ``LocalExecutor``; ``"auto"`` -> a mesh
     over every visible device; an int(-string) N -> a mesh over the first
-    min(N, visible) devices.
+    N devices (``ValueError`` when fewer are visible).
     """
     if spec is None or spec in ("none", "local"):
         return LocalExecutor()

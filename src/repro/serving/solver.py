@@ -24,6 +24,7 @@ pure O(n log n) reorder -- no per-problem dynamic shapes anywhere.
 """
 from __future__ import annotations
 
+import functools
 from typing import Callable, NamedTuple, Optional
 
 import jax
@@ -32,6 +33,7 @@ import numpy as np
 
 from repro.core.jacobi import DEFAULT_SWEEPS, jacobi_eigh
 from repro.core.pca import PCAConfig, evcr_cvcr
+from repro.core.precision import matmul_precision
 
 
 class BatchedEighResult(NamedTuple):
@@ -85,6 +87,22 @@ def _masked_sort(w, V, n_active):
     return w, V
 
 
+def _canonical_sign(V):
+    """Flip each eigenvector column so its largest-magnitude entry is
+    positive.
+
+    An eigenvector is defined up to sign, and which sign a Jacobi solve
+    lands on depends on rounding: the same problem compiled for one device
+    and for a sharded mesh can come back with opposite columns.  Fixing
+    the sign makes served results placement-stable (equal up to rounding,
+    not up to sign).  Padded columns are exact basis vectors e_j and keep
+    their +1.
+    """
+    pivot = jnp.take_along_axis(
+        V, jnp.argmax(jnp.abs(V), axis=0)[None, :], axis=0)[0]
+    return V * jnp.where(pivot < 0, -1.0, 1.0).astype(V.dtype)[None, :]
+
+
 def jacobi_eigh_batched(
     C,
     n_active=None,
@@ -122,6 +140,7 @@ def jacobi_eigh_batched(
     w, V = res.eigenvalues, res.eigenvectors
     if sort:
         w, V = jax.vmap(_masked_sort)(w, V, n_active)
+    V = jax.vmap(_canonical_sign)(V)
     return BatchedEighResult(w, V, res.off_norm, n_active)
 
 
@@ -156,7 +175,8 @@ def jacobi_svd_batched(
     B, mb, nb = A.shape
     n_rows = _as_n_active(n_rows, B, mb)
     n_cols = _as_n_active(n_cols, B, nb)
-    mm = matmul_fn or jnp.matmul
+    mm = matmul_fn or functools.partial(
+        jnp.matmul, precision=matmul_precision(precision))
     if fused:
         from repro.kernels import ops as kops
         gram = jax.vmap(lambda a: kops.covariance(
@@ -220,7 +240,8 @@ def pca_fit_batched(
     B, mb, db = X.shape
     n_rows = _as_n_active(n_rows, B, mb)
     n_cols = _as_n_active(n_cols, B, db)
-    mm = config.matmul_fn() or jnp.matmul
+    mm = config.matmul_fn() or functools.partial(
+        jnp.matmul, precision=matmul_precision(config.precision))
 
     if config.standardize:
         Xs, mean, scale = jax.vmap(_masked_standardize)(X, n_rows, n_cols)

@@ -18,7 +18,8 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def run_in_mesh_subprocess(body: str, device_count: int = 8) -> dict:
     """Run ``body`` in a child with ``device_count`` forced host devices.
 
-    The child gets json/numpy/jax/jnp pre-imported; it must print a JSON
+    The child gets json/numpy/jax/jnp and ``make_mesh`` (Auto-axis
+    meshes) pre-imported; it must print a JSON
     object as its last stdout line, which is returned parsed.
     """
     prog = textwrap.dedent(f"""
@@ -28,6 +29,7 @@ def run_in_mesh_subprocess(body: str, device_count: int = 8) -> dict:
         import json
         import numpy as np
         import jax, jax.numpy as jnp
+        from repro.parallel.sharding import make_mesh
     """) + textwrap.dedent(body)
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(REPO, "src")

@@ -10,7 +10,7 @@ from _mesh import run_in_mesh_subprocess as _run
 def test_distributed_covariance_matches_local():
     out = _run("""
         from repro.core import covariance, distributed_covariance
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         rng = np.random.default_rng(0)
         x = jnp.asarray(rng.standard_normal((256, 24)), jnp.float32)
         c_dist = distributed_covariance(x, mesh, block_m=16)
@@ -24,7 +24,7 @@ def test_distributed_covariance_matches_local():
 def test_distributed_pca_matches_numpy():
     out = _run("""
         from repro.core import PCAConfig, fit_distributed
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         rng = np.random.default_rng(1)
         x = (rng.standard_normal((256, 4)) @
              rng.standard_normal((4, 12))).astype(np.float32)
@@ -56,7 +56,7 @@ def test_sharded_train_step_matches_single_device():
 
         cfg = dataclasses.replace(reduced_config("granite-8b"), tp=4,
                                   n_layers=2)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         shape = ShapeCell("t", 32, 4, "train")
         step, in_sh, out_sh, _, rules = steps_mod.build_train_step(
             cfg, mesh, shape)
@@ -100,7 +100,7 @@ def test_moe_shard_map_matches_single_device():
         cfg = dataclasses.replace(reduced_config("arctic-480b"), tp=4,
                                   n_layers=1, n_experts=8,
                                   capacity_factor=4.0)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         rules = rules_for_mesh(mesh)
         key = jax.random.PRNGKey(0)
         p = jax.tree.map(lambda x: x.v if hasattr(x, "v") else x,
@@ -135,7 +135,7 @@ def test_seq_sharded_decode_matches_replicated():
         from repro.parallel.sharding import REPLICATED, Rules
 
         cfg = dataclasses.replace(reduced_config("granite-8b"), n_layers=2)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         rules = Rules(mesh_axes=("data", "model"), mesh=mesh,
                       seq_over_data=False)
         params = tfm.param_values(tfm.init_model(jax.random.PRNGKey(0), cfg))
@@ -167,12 +167,12 @@ def test_elastic_restore_across_meshes(tmp_path):
         from repro.checkpoint import checkpointer
 
         d = pathlib.Path({str(tmp_path)!r})
-        mesh_a = jax.make_mesh((4, 2), ("data", "model"))
+        mesh_a = make_mesh((4, 2), ("data", "model"))
         sh_a = NamedSharding(mesh_a, P("data", "model"))
         w = jax.device_put(jnp.arange(64.0).reshape(8, 8), sh_a)
         checkpointer.save(d, 3, {{"w": w}}, metadata={{"step": 3}})
 
-        mesh_b = jax.make_mesh((2, 2), ("data", "model"),
+        mesh_b = make_mesh((2, 2), ("data", "model"),
                                devices=jax.devices()[:4])
         sh_b = NamedSharding(mesh_b, P("model", "data"))
         restored, meta = checkpointer.restore(
@@ -203,7 +203,7 @@ def test_moe_fused_dense_residual_matches_single_device():
         cfg = dataclasses.replace(reduced_config("arctic-480b"), tp=4,
                                   n_layers=1, n_experts=8,
                                   capacity_factor=4.0)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         rules = rules_for_mesh(mesh)
         strip = lambda t: jax.tree.map(
             lambda x: x.v if hasattr(x, "v") else x, t,
@@ -233,9 +233,8 @@ def test_ring_attention_matches_dense():
     handle without padding)."""
     out = _run("""
         from repro.parallel.ring_attention import ring_attention
-        from repro.parallel.sharding import use_mesh
         from repro.models.attention import _dense_attention
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         rng = np.random.default_rng(0)
         B, S, H, D = 4, 64, 6, 16
         q = jnp.asarray(rng.standard_normal((B, S, H, D)), jnp.float32)
@@ -243,7 +242,7 @@ def test_ring_attention_matches_dense():
         v = jnp.asarray(rng.standard_normal((B, S, H, D)), jnp.float32)
         errs = {}
         for causal in (True, False):
-            with use_mesh(mesh):
+            with jax.set_mesh(mesh):
                 o = jax.jit(lambda q, k, v: ring_attention(
                     q, k, v, mesh, causal=causal))(q, k, v)
                 o = jax.device_get(o)
@@ -261,18 +260,18 @@ def test_ring_mode_model_matches_chunked():
         import dataclasses
         from repro.configs import reduced_config
         from repro.models import transformer as tfm
-        from repro.parallel.sharding import REPLICATED, rules_for_mesh, use_mesh
+        from repro.parallel.sharding import REPLICATED, rules_for_mesh
 
         cfg_r = dataclasses.replace(reduced_config("qwen1.5-32b"), tp=4,
                                     n_layers=2, attn_impl="ring")
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         rules = rules_for_mesh(mesh)
         params = tfm.param_values(tfm.init_model(jax.random.PRNGKey(0),
                                                  cfg_r))
         rng = np.random.default_rng(0)
         batch = {"tokens": jnp.asarray(
             rng.integers(0, cfg_r.vocab_size, (4, 32)), jnp.int32)}
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             lr = jax.device_get(jax.jit(lambda p, b: tfm.forward(
                 p, b, cfg_r, rules, "train")[0])(params, batch))
         cfg_c = dataclasses.replace(cfg_r, tp=1, attn_impl="chunked")
@@ -288,15 +287,14 @@ def test_ring_attention_gqa_rotates_true_kv():
     attention with expanded KV."""
     out = _run("""
         from repro.parallel.ring_attention import ring_attention
-        from repro.parallel.sharding import use_mesh
         from repro.models.attention import _dense_attention
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         rng = np.random.default_rng(1)
         B, S, H, KV, D = 2, 64, 8, 2, 16
         q = jnp.asarray(rng.standard_normal((B, S, H, D)), jnp.float32)
         k = jnp.asarray(rng.standard_normal((B, S, KV, D)), jnp.float32)
         v = jnp.asarray(rng.standard_normal((B, S, KV, D)), jnp.float32)
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             o = jax.jit(lambda q, k, v: ring_attention(
                 q, k, v, mesh, causal=True))(q, k, v)
             o = jax.device_get(o)

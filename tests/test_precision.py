@@ -122,3 +122,22 @@ def test_bf16_halves_streamed_bytes():
     """The policy's entire value: the operand panels stream at 2 bytes."""
     assert jnp.dtype(prec.operand_dtype("bf16_fp32acc")).itemsize == 2
     assert jnp.dtype(prec.acc_dtype("bf16_fp32acc")).itemsize == 4
+
+
+@pytest.mark.parametrize("op", ["pca", "svd"])
+@pytest.mark.parametrize("policy,highest", [("fp32", True),
+                                            ("bf16_fp32acc", False)])
+def test_fp32_policy_matmuls_run_at_highest_precision(op, policy, highest):
+    """A TPU runs an fp32 dot at DEFAULT precision as one bf16 pass, which
+    misses the fp32 budgets; the fp32 policy's plain matmuls therefore
+    carry HIGHEST into the lowered program, and the bf16 lane does not."""
+    from repro.serving.solver import build_solver_fn
+    fn = build_solver_fn(op, PCAConfig(T=8, S=2, sweeps=2,
+                                       precision=policy))
+    structs = (jax.ShapeDtypeStruct((2, 32, 8), jnp.float32),
+               jax.ShapeDtypeStruct((2,), jnp.int32),
+               jax.ShapeDtypeStruct((2,), jnp.int32))
+    text = jax.jit(fn).lower(*structs).as_text()
+    assert ("HIGHEST" in text) == highest
+    assert prec.matmul_precision(policy) == (
+        jax.lax.Precision.HIGHEST if highest else None)

@@ -16,15 +16,12 @@ import jax.numpy as jnp
 
 from repro.core import PCAConfig
 from repro.serving import (BucketPolicy, LRUCache, PCAServer, ServingPlan,
-                           SolverKey, TrafficProfile, aot_supported,
-                           jacobi_svd_batched)
+                           SolverKey, TrafficProfile, jacobi_svd_batched)
 import repro.serving.cache as cache_mod
 import repro.serving.sharded as sharded_mod
 from repro.obs import Observability
 
 REPO_ROOT = pathlib.Path(__file__).resolve().parent.parent
-aot = pytest.mark.skipif(not aot_supported(),
-                         reason="jax lacks serialize_executable")
 
 
 def _sym(n, seed=0):
@@ -209,7 +206,6 @@ def test_apply_plan_prewarms_incoming_executables():
 # persistent tier
 # ---------------------------------------------------------------------------
 
-@aot
 def test_disk_tier_round_trip_is_bitwise_identical(tmp_path):
     mats = [_sym(6), _sym(7, seed=1)]
     seeder = _server(tmp_path)
@@ -227,7 +223,6 @@ def test_disk_tier_round_trip_is_bitwise_identical(tmp_path):
     _assert_results_equal(expect, _server().solve_many(mats, op="eigh"))
 
 
-@aot
 def test_corrupt_cache_entry_falls_back_and_repairs(tmp_path):
     mats = [_sym(6)]
     expect = _server(tmp_path).solve_many(mats, op="eigh")
@@ -249,16 +244,16 @@ def test_corrupt_cache_entry_falls_back_and_repairs(tmp_path):
     assert disk["hits"] >= 1 and disk["errors"] == 0
 
 
-@aot
 def test_environment_drift_invalidates_cleanly(tmp_path, monkeypatch):
-    """A different (jax version, device backend) fingerprint hashes to a
-    different file name: the stale entry is simply never looked up."""
+    """A different (jax version, device backend, device kind) fingerprint
+    hashes to a different file name: the stale entry is simply never
+    looked up."""
     mats = [_sym(6)]
     _server(tmp_path).solve_many(mats, op="eigh")
     before = set(tmp_path.glob("*.jexec"))
 
     monkeypatch.setattr(cache_mod, "environment_fingerprint",
-                        lambda: ("jax-9.9.9", "quantum"))
+                        lambda: ("jax-9.9.9", "quantum", "QPU v1"))
     srv = _server(tmp_path)
     srv.solve_many(mats, op="eigh")
     disk = srv.cache_summary()["disk"]
@@ -267,7 +262,6 @@ def test_environment_drift_invalidates_cleanly(tmp_path, monkeypatch):
     assert set(tmp_path.glob("*.jexec")) > before   # stored under new hash
 
 
-@aot
 def test_header_version_mismatch_is_quarantined(tmp_path):
     """Defense in depth: even if the hash collided across environments,
     the in-file header is checked and a drifted entry is rejected."""
@@ -275,7 +269,7 @@ def test_header_version_mismatch_is_quarantined(tmp_path):
     expect = _server(tmp_path).solve_many(mats, op="eigh")
     path = next(iter(tmp_path.glob("*.jexec")))
     record = pickle.loads(path.read_bytes())
-    record["jax"] = "0.0.1"
+    record["env"] = ("0.0.1",) + tuple(record["env"][1:])
     path.write_bytes(pickle.dumps(record))
 
     srv = _server(tmp_path)
@@ -283,6 +277,28 @@ def test_header_version_mismatch_is_quarantined(tmp_path):
     _assert_results_equal(expect, got)
     disk = srv.cache_summary()["disk"]
     assert disk["errors"] >= 1 and disk["stores"] >= 1
+
+
+def test_fingerprint_keys_device_kind(tmp_path):
+    """An executable serialized for one chip generation is never loaded on
+    another: the device kind is in both the content hash and the header,
+    and an entry whose header names another kind is quarantined."""
+    import jax
+    env = cache_mod.environment_fingerprint()
+    assert env == (jax.__version__, jax.default_backend(),
+                   jax.devices()[0].device_kind)
+    mats = [_sym(6)]
+    expect = _server(tmp_path).solve_many(mats, op="eigh")
+    path = next(iter(tmp_path.glob("*.jexec")))
+    record = pickle.loads(path.read_bytes())
+    assert tuple(record["env"]) == env
+    record["env"] = env[:2] + ("TPU v4",)
+    path.write_bytes(pickle.dumps(record))
+
+    srv = _server(tmp_path)
+    _assert_results_equal(expect, srv.solve_many(mats, op="eigh"))
+    disk = srv.cache_summary()["disk"]
+    assert disk["hits"] == 0 and disk["errors"] == 1
 
 
 _WARMER = """\
@@ -298,7 +314,6 @@ print("warmed")
 """
 
 
-@aot
 def test_concurrent_warmers_share_one_cache_dir(tmp_path):
     """Two replicas warming the same --cache-dir concurrently must not
     torch each other's entries (atomic write-then-rename)."""
@@ -322,7 +337,6 @@ def test_concurrent_warmers_share_one_cache_dir(tmp_path):
     assert srv.cache_summary()["disk"]["errors"] == 0
 
 
-@aot
 def test_disk_cache_size_cap_evicts_down_to_cap(tmp_path):
     fn = jax.jit(lambda x: x + 1.0).lower(
         jax.ShapeDtypeStruct((4,), jnp.float32)).compile()

@@ -1,6 +1,6 @@
 """Sharded serving: MeshExecutor parity with the single-device flush path,
 executor-qualified cache keys, partial-flush padding to the data-axis
-multiple, and graceful degradation when fewer devices are visible.
+multiple, and refusal when fewer devices are visible than requested.
 
 Multi-device cases follow the ``test_distributed.py`` recipe -- a
 subprocess forcing ``--xla_force_host_platform_device_count=8`` -- so they
@@ -114,11 +114,15 @@ def test_mesh_executor_rounds_and_validates_batch():
 
 
 def test_mesh_executor_spec_degrades_to_visible_devices():
-    """Asking for more devices than visible clamps instead of raising, so
-    one launch line works from a laptop to the 8-device CI job."""
-    ex = mesh_executor(str(jax.device_count() * 4))
-    assert isinstance(ex, MeshExecutor)
-    assert ex.n_shards == jax.device_count()
+    """Asking for more devices than are visible no longer degrades: it
+    raises, naming both counts, instead of quietly running on fewer.
+    "auto" is the spelling that takes whatever is visible."""
+    n = jax.device_count()
+    with pytest.raises(ValueError, match=f"{n * 4}-device mesh.*only {n}"):
+        mesh_executor(str(n * 4))
+    with pytest.raises(ValueError, match="requested"):
+        host_mesh(n + 1)
+    assert mesh_executor("auto").n_shards == n
     assert mesh_executor("none").n_shards == 1
     assert mesh_executor("1").n_shards == 1
     assert not isinstance(mesh_executor("1"), MeshExecutor)
@@ -159,7 +163,10 @@ def test_multi_device_flush_in_process():
 def test_sharded_flush_matches_single_device_all_ops():
     """Sharded parity -- and, since the sharded server runs a deep
     pipeline (max_inflight=3), async-over-mesh parity: in-flight sharded
-    flushes must retire to exactly the synchronous local results."""
+    flushes must retire to the synchronous local results.  The sharded
+    and single-device executables are separate compiles, so they agree to
+    rounding, not bit for bit; eigenvector signs are canonical, so no
+    column may come back flipped."""
     out = _run("""
         from repro.core import PCAConfig
         from repro.serving import (BucketPolicy, MeshExecutor, PCAServer,
@@ -186,9 +193,13 @@ def test_sharded_flush_matches_single_device_all_ops():
                 fields = [f.name for f in dataclasses.fields(g)]
                 assert fields, op
                 for f in fields:
-                    err = max(err, float(np.max(np.abs(
-                        np.asarray(getattr(g, f), np.float64)
-                        - np.asarray(getattr(w, f), np.float64)))))
+                    a = np.asarray(getattr(g, f), np.float64)
+                    b = np.asarray(getattr(w, f), np.float64)
+                    # relative to the field's scale (unit floor): the two
+                    # compiles round differently, so eigenvalues near 35
+                    # differ in their last bits
+                    scale = max(1.0, float(np.max(np.abs(b))))
+                    err = max(err, float(np.max(np.abs(a - b))) / scale)
             errs[op] = err
         errs["n_shards"] = sorted({r.n_shards
                                    for r in sharded.stats.records})
