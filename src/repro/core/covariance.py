@@ -2,7 +2,9 @@
 
 The contraction dimension of X^T X is the *sample* axis M, so streaming
 T-sized sample blocks keeps the on-chip working set constant regardless of
-dataset size -- the paper's scale-invariance claim.  Three paths:
+dataset size -- the paper's scale-invariance claim.  Three paths, each
+under the ``covariance`` name scope (the ``op_name`` of its operations in
+HLO metadata and in device traces):
 
   * ``covariance``            -- plain jnp (oracle / CPU path)
   * ``blocked_covariance``    -- explicit block-streaming accumulation
@@ -35,6 +37,7 @@ def standardize(X, eps: float = 1e-8) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.nda
     return (X - mean) / std, mean, std
 
 
+@jax.named_scope("covariance")
 def covariance(X, normalize: bool = False) -> jnp.ndarray:
     """C = X^T X (paper eq. 2); ``normalize`` divides by (M - 1)."""
     C = X.T @ X
@@ -43,6 +46,7 @@ def covariance(X, normalize: bool = False) -> jnp.ndarray:
     return C
 
 
+@jax.named_scope("covariance")
 def blocked_covariance(
     X,
     block_m: int = 128,

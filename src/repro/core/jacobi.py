@@ -24,6 +24,10 @@ Two rotation-application modes:
 
 Convergence: fixed deterministic sweep count (default 50, the paper's safety
 schedule) with optional software early-exit tolerance.
+
+Name scopes ``jacobi_sweep``, ``jacobi_round`` and ``jacobi_rotation``
+mark one sweep, one pivot round and the rotation apply in the ``op_name``
+of the compiled operations, so a device trace can be reduced by stage.
 """
 from __future__ import annotations
 
@@ -122,6 +126,7 @@ def _null_pivot_guard(p, q, apq, c, s):
     return c, s
 
 
+@jax.named_scope("jacobi_rotation")
 def _apply_rotations_rowcol(C, V, p, q, c, s):
     """Apply commuting rotations for disjoint pivot sets (vectorised).
 
@@ -145,6 +150,7 @@ def _apply_rotations_rowcol(C, V, p, q, c, s):
     return C, V
 
 
+@jax.named_scope("jacobi_rotation")
 def _apply_rotations_matmul(C, V, p, q, c, s, matmul_fn):
     n = C.shape[0]
     J = _build_rotation(n, p, q, c, s, C.dtype)
@@ -169,12 +175,14 @@ def _sweep_scan(C, V, rounds, angle_fn, rotation, matmul_fn,
     if fused and rotation == "rowcol":
         from repro.kernels import ops as kops
 
+        @jax.named_scope("jacobi_round")
         def body(carry, pairs):
             C, V = carry
             C, V = kops.jacobi_sweep(C, V, pairs, angle=angle,
                                      backend=fused_backend)
             return (C, V), None
     else:
+        @jax.named_scope("jacobi_round")
         def body(carry, pairs):
             C, V = carry
             p = pairs[:, 0]
@@ -200,6 +208,7 @@ def _max_pivot_sweep(C, V, n_rot: int, angle_fn, rotation, matmul_fn,
                      pivot_fn=dle_mod.find_pivot):
     """n_rot classical max-pivot rotations (DLE lookup per rotation)."""
 
+    @jax.named_scope("jacobi_round")
     def body(_, carry):
         C, V = carry
         piv = pivot_fn(C)
@@ -284,6 +293,7 @@ def jacobi_eigh(
         rounds = None
         rot_per_sweep = (n_in * (n_in - 1)) // 2  # one "sweep" worth
 
+    @jax.named_scope("jacobi_sweep")
     def one_sweep(C, V):
         if pivot == "paper":
             return _max_pivot_sweep(C, V, rot_per_sweep, angle_fn, rotation,

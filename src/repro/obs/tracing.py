@@ -8,14 +8,24 @@ module records one span per pipeline stage into a bounded ring buffer:
   request   submit -> fulfil, with a "queued" child covering the
             pre-dispatch wait; linked (``parent``) to the flush span
             that retired it.
-  flush     dispatch -> retire-complete, with "dispatch" (stack / pad /
-            cache-lookup / launch), "inflight" (launched, host free),
-            "wait" (blocked on the device) and "retire" (gather / unpack /
-            fulfil) children.  On a cache miss the executable build gets
-            its own "compile" child; the XLA compilation itself runs
-            inside the miss flush's first launch, so its cost lands in
-            that flush's dispatch span.
+  flush     dispatch -> retire-complete, with "dispatch" (its "stack",
+            "lookup", "put" and "launch" children), "inflight" (launched,
+            host free), "wait" (blocked on the device), "fetch" (the
+            gather home) and "retire" (its "unpack" child: unpack /
+            fulfil) children, all rebuilt from the flush's
+            ``FlushRecord`` stamps with no clock reads of their own.  On
+            a cache miss the executable build gets its own "compile"
+            child; the XLA compilation itself runs inside the miss
+            flush's first launch, so its cost lands in that flush's
+            launch span.
   control   plan swaps (``PCAServer.apply_plan``) and autotune searches.
+
+The engine times those stages with ``stage``, which also enters a
+``jax.profiler.TraceAnnotation`` named ``serve.<stage>``.  That half is
+always on and costs about a microsecond while no profiler session runs;
+under one, the spans land on the host plane of the profile, on the same
+clock as the device's operations, so a device idle gap can be put down to
+the program stage the host was in.
 
 Recording is O(1) per span (an append into a ``deque(maxlen=...)``); a
 long-running server's trace is the *most recent* window, never unbounded.
@@ -43,10 +53,49 @@ import json
 import pathlib
 import time
 from collections import deque
-from typing import Deque, Dict, List, Optional, Tuple
+from typing import Callable, Deque, Dict, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 # export-time comparison slack for float timestamps (seconds)
 _EPS = 1e-9
+
+# name prefix of the serving engine's profiler annotations
+STAGE_PREFIX = "serve."
+
+
+class stage:
+    """Time one program stage on the server's clock and in the profiler.
+
+    ``with stage("stack", clock, start=t0) as st: ...`` enters the
+    ``TraceAnnotation`` ``serve.stack`` and leaves the stage's stamps in
+    ``st.start`` and ``st.end``.  ``start`` reuses a stamp the caller
+    already read (the previous stage's end), so back-to-back stages cost
+    one clock read each; without it the stage reads its own.
+    """
+
+    __slots__ = ("_note", "_clock", "start", "end")
+
+    def __init__(self, name: str, clock: Callable[[], float],
+                 start: Optional[float] = None):
+        self._note = TraceAnnotation(STAGE_PREFIX + name)
+        self._clock = clock
+        self.start = start
+        self.end: Optional[float] = None
+
+    def __enter__(self) -> "stage":
+        self._note.__enter__()
+        if self.start is None:
+            self.start = self._clock()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.end = self._clock()
+        self._note.__exit__(*exc)
+
+    @property
+    def seconds(self) -> float:
+        return self.end - self.start
 
 
 @dataclasses.dataclass(frozen=True)

@@ -215,7 +215,7 @@ class TrafficProfile:
         hit = [f.dispatch_s for f in fr if f.cache_hit]
         miss = [f.dispatch_s for f in fr if not f.cache_hit]
         overlap_s = float(sum(f.overlap_s for f in fr))
-        inflight_s = overlap_s + float(sum(f.wait_s for f in fr))
+        inflight_s = float(sum(f.inflight_s for f in fr))
         return cls(
             shape_counts=shape_counts,
             requests=len(recs),

@@ -18,10 +18,17 @@ block-streaming (keep the S arrays busy while the next block streams in):
              straight back to batching while the device crunches.
   in-flight  a bounded ``inflight.InFlightQueue`` of launched flushes
              (``max_inflight`` is the back-pressure valve).
-  retire     ``_retire``: one host gather per flush, unpack into tickets,
-             record telemetry.  ``poll``/``drain`` retire completed
-             flushes; ``Ticket.result()``/``Ticket.wait()`` force exactly
-             their own flush home.
+  retire     ``_retire``: block on the device, one host gather per flush,
+             unpack into tickets, record telemetry.  ``poll``/``drain``
+             retire completed flushes; ``Ticket.result()``/
+             ``Ticket.wait()`` force exactly their own flush home.
+
+Each step of a flush runs inside ``repro.obs.tracing.stage``: ``stack``,
+``lookup``, ``put`` and ``launch`` in dispatch, ``wait``, ``fetch`` and
+``unpack`` in retire.  Their stamps land in the flush's ``FlushRecord``
+on the server's clock, whether or not an ``obs`` bundle is attached, and
+under a ``jax.profiler`` session each is a ``serve.<stage>`` span on the
+host plane of the profile.
 
 With ``max_inflight=1`` (the default) every dispatch immediately retires
 its own flush -- exactly the synchronous engine this pipeline replaced --
@@ -46,11 +53,12 @@ from typing import Callable, Dict, List, Optional, Tuple
 import numpy as np
 
 from repro.core.pca import PCAConfig
+from repro.obs.tracing import stage
 from .batching import BucketPolicy, padding_waste, stack_requests
 from .cache import DEFAULT_MAX_ENTRIES, ExecutableCache, SolverKey
 from .inflight import InFlightFlush, InFlightQueue
 from .sharded import LocalExecutor
-from .stats import RequestRecord, ServingStats
+from .stats import FlushRecord, RequestRecord, ServingStats
 
 OPS = ("eigh", "svd", "pca")
 
@@ -277,8 +285,8 @@ class PCAServer:
         the oldest flush first.
       obs: optional ``repro.obs.Observability`` bundle.  When given, every
         pipeline stage emits spans (request submit->fulfil, flush
-        dispatch/inflight/wait/retire with compile children on cache
-        misses, plan swaps) into its tracer and per-(op, bucket, backend,
+        dispatch/inflight/wait/fetch/retire with their stage and compile
+        children, plan swaps) into its tracer and per-(op, bucket, backend,
         executor) counters/histograms into its metric registry, and each
         fulfilled request is SLO-accounted.  ``None`` (the default) is the
         uninstrumented fast path: one attribute check per stage, measured
@@ -384,8 +392,7 @@ class PCAServer:
 
     def _wire_obs(self) -> None:
         """Create the engine's metric families once (per-call recording is
-        then a dict lookup) and hand the executor the bundle so launches
-        are traced where they happen."""
+        then a dict lookup)."""
         m = self.obs.metrics
         self._m_submitted = m.counter(
             "serve_requests_total", "Requests accepted by submit().",
@@ -426,8 +433,6 @@ class PCAServer:
             "serve_warmup_executables_total",
             "Executables pre-built by warmup(), by cache source.",
             ("source",))
-        if getattr(self.executor, "obs", None) is None:
-            self.executor.obs = self.obs
 
     # -- request path -------------------------------------------------------
     def submit(self, matrix, op: str = "eigh",
@@ -619,8 +624,6 @@ class PCAServer:
         now = self.clock()
         self.stats.record_plan_switch(switch, now=now)
         if self.obs is not None:
-            if getattr(self.executor, "obs", None) is None:
-                self.executor.obs = self.obs
             self._m_swaps.inc(now=now)
             self.obs.tracer.complete(
                 "plan_swap", ts=t_swap, end=now, cat="control",
@@ -648,48 +651,51 @@ class PCAServer:
         queue = self._queues.pop(key, [])
         if not queue:
             return 0
-        t_dispatch = self.clock()
-        batch, n_active = stack_requests([e.matrix for e in queue], bucket)
+        clock = self.clock
+        t_dispatch = clock()
         b = len(queue)
-        bp = max(self.max_batch if self.pad_batches else b, b)
-        # the executor may demand a larger batch (a mesh pads up to the
-        # next data-axis multiple so every shard gets an identical slab)
-        bp = self.executor.round_batch(bp)
-        if bp > b:  # inert filler: zero matrices with zero live coordinates
-            batch = np.concatenate(
-                [batch, np.zeros((bp - b, *bucket), batch.dtype)])
-            n_active = np.concatenate(
-                [n_active, np.zeros((n_active.shape[0], bp - b), np.int32)],
-                axis=1)
+        with stage("stack", clock, t_dispatch) as stack:
+            batch, n_active = stack_requests([e.matrix for e in queue],
+                                             bucket)
+            bp = max(self.max_batch if self.pad_batches else b, b)
+            # the executor may demand a larger batch (a mesh pads up to the
+            # next data-axis multiple so every shard gets an identical slab)
+            bp = self.executor.round_batch(bp)
+            if bp > b:  # inert filler: zero matrices, zero live coordinates
+                batch = np.concatenate(
+                    [batch, np.zeros((bp - b, *bucket), batch.dtype)])
+                n_active = np.concatenate(
+                    [n_active,
+                     np.zeros((n_active.shape[0], bp - b), np.int32)],
+                    axis=1)
         backend = self.backend_for(op, bucket)
+        with stage("lookup", clock, stack.end) as lookup:
+            fn, source = self._executable(op, bucket, bp, backend, sweeps)
         obs = self.obs
         if obs is not None:
-            # reserve the flush span's id now so the compile/launch spans
-            # recorded below can name it as their parent; the span itself
-            # is recorded at retire time, when its end is known
+            # reserve the flush span's id now; the span itself is recorded
+            # at retire time, when its end is known
             flush_span = obs.tracer.new_id()
-            t0 = self.clock()
-            fn, source = self._executable(op, bucket, bp, backend, sweeps)
             if source != "memory":
                 # the executable *build*: a jit-wrapper construction on the
                 # memory-only path (XLA itself compiles lazily inside the
-                # first launch, landing in the dispatch span), a full AOT
+                # first launch, landing in the launch span), a full AOT
                 # compile when the disk tier is armed, or a deserialize on
                 # a disk hit ("aot_load")
                 obs.tracer.complete(
                     "compile" if source == "compile" else "aot_load",
-                    ts=t0, end=self.clock(), cat="compile",
+                    ts=lookup.start, end=lookup.end, cat="compile",
                     track="flushes", parent=flush_span, op=op,
                     bucket=list(bucket), batch=bp, backend=str(backend))
-        else:
-            fn, source = self._executable(op, bucket, bp, backend, sweeps)
         hit = source != "compile"
-        flush = self.executor.submit(fn, batch, n_active)
+        flush = self.executor.submit(fn, batch, n_active, clock=clock,
+                                     start=lookup.end)
         flush.seq = next(self._seq)
         flush.key = key
         flush.entries = tuple(queue)
         flush.t_dispatch = t_dispatch
-        flush.t_launched = self.clock()
+        flush.stack_s = stack.seconds
+        flush.lookup_s = lookup.seconds
         flush.backend = backend
         flush.batch_size = b
         flush.padded_batch = bp
@@ -727,44 +733,54 @@ class PCAServer:
         if flush.retired:
             return 0
         op, bucket, sweeps = flush.key
-        t_wait = self.clock()
-        out = flush.result()
-        t_retire = self.clock()
+        clock = self.clock
+        t_wait = clock()
+        with stage("wait", clock, t_wait) as wait:
+            flush.block_until_ready()
+        with stage("fetch", clock, wait.end) as fetch:
+            out = flush.result()
+        t_retire = fetch.end
         flush.retired = True
         self._inflight.remove(flush)
-        self.stats.record_flush(
+        records = []
+        with stage("unpack", clock, t_retire) as unpack:
+            for i, e in enumerate(flush.entries):
+                rec = RequestRecord(
+                    rid=e.rid, op=op, shape=e.matrix.shape, bucket=bucket,
+                    batch_size=flush.batch_size, cache_hit=flush.cache_hit,
+                    t_submit=e.t_submit, t_done=t_retire,
+                    queue_s=flush.t_dispatch - e.t_submit,
+                    padding_waste=padding_waste(e.matrix.shape, bucket),
+                    backend=flush.backend, n_shards=flush.n_shards,
+                    t_dispatch=flush.t_dispatch,
+                    inflight_depth=flush.inflight_depth,
+                    deadline=e.flush_by, sweeps=sweeps)
+                e.ticket._fulfil(self._unpack(op, out, i, e.matrix.shape),
+                                 rec)
+                self.stats.record_request(rec)
+                records.append(rec)
+        fr = self.stats.record_flush(
             flush.cache_hit, t_dispatch=flush.t_dispatch,
-            t_launched=flush.t_launched, t_wait=t_wait, t_retire=t_retire,
+            t_put=flush.t_put, t_launched=flush.t_launched, t_wait=t_wait,
+            t_ready=wait.end, t_retire=t_retire, t_done=unpack.end,
+            stack_s=flush.stack_s, lookup_s=flush.lookup_s,
             batch_size=flush.batch_size,
             inflight_depth=flush.inflight_depth,
             op=op, bucket=bucket, padded_batch=flush.padded_batch)
-        records = []
-        for i, e in enumerate(flush.entries):
-            rec = RequestRecord(
-                rid=e.rid, op=op, shape=e.matrix.shape, bucket=bucket,
-                batch_size=flush.batch_size, cache_hit=flush.cache_hit,
-                t_submit=e.t_submit, t_done=t_retire,
-                queue_s=flush.t_dispatch - e.t_submit,
-                padding_waste=padding_waste(e.matrix.shape, bucket),
-                backend=flush.backend, n_shards=flush.n_shards,
-                t_dispatch=flush.t_dispatch,
-                inflight_depth=flush.inflight_depth,
-                deadline=e.flush_by, sweeps=sweeps)
-            e.ticket._fulfil(self._unpack(op, out, i, e.matrix.shape), rec)
-            self.stats.record_request(rec)
-            records.append(rec)
         if self.obs is not None:
-            self._record_obs(flush, records, t_wait, t_retire)
+            self._record_obs(flush, records, fr)
         return len(flush.entries)
 
     def _record_obs(self, flush: InFlightFlush, records: List[RequestRecord],
-                    t_wait: float, t_retire: float) -> None:
+                    fr: FlushRecord) -> None:
         """Emit the retired flush's spans and metrics (obs attached only).
 
         One flush span (dispatch -> retire-complete) with dispatch /
-        inflight / wait / retire children, then one request span per
-        fulfilled ticket, parented to the flush span -- the link that ties
-        a request's latency to the microbatch that actually served it.
+        inflight / wait / fetch / retire children, the dispatch and retire
+        children broken into their stages, all from the flush record's
+        stamps; then one request span per fulfilled ticket, parented to
+        the flush span -- the link that ties a request's latency to the
+        microbatch that actually served it.
         """
         obs = self.obs
         tr = obs.tracer
@@ -774,24 +790,36 @@ class PCAServer:
         fid = flush.span_id if flush.span_id is not None else tr.new_id()
         bucket_l = list(bucket)
         tr.complete(
-            f"flush:{op}", ts=flush.t_dispatch, end=t_end, cat="flush",
+            f"flush:{op}", ts=fr.t_dispatch, end=t_end, cat="flush",
             track="flushes", id=fid, op=op, bucket=bucket_l,
             batch=flush.batch_size, padded_batch=flush.padded_batch,
             backend=str(backend), executor=exec_label,
             cache_hit=flush.cache_hit, n_shards=flush.n_shards,
             inflight_depth=flush.inflight_depth, seq=flush.seq)
-        tr.complete("dispatch", ts=flush.t_dispatch, end=flush.t_launched,
-                    cat="flush", track="flushes", parent=fid,
+        did, rid = tr.new_id(), tr.new_id()
+        tr.complete("dispatch", ts=fr.t_dispatch, end=fr.t_launched,
+                    cat="flush", track="flushes", parent=fid, id=did,
                     cache_hit=flush.cache_hit)
-        tr.complete("inflight", ts=flush.t_launched, end=t_wait,
-                    cat="flush", track="flushes", parent=fid)
-        tr.complete("wait", ts=t_wait, end=t_retire, cat="flush",
-                    track="flushes", parent=fid)
-        tr.complete("retire", ts=t_retire, end=t_end, cat="flush",
-                    track="flushes", parent=fid,
+        tr.complete("retire", ts=fr.t_retire, end=t_end, cat="flush",
+                    track="flushes", parent=fid, id=rid,
                     requests=len(records))
+        t_stacked = fr.t_dispatch + fr.stack_s
+        t_looked = t_stacked + fr.lookup_s
+        for name, ts, end, parent in (
+                ("stack", fr.t_dispatch, t_stacked, did),
+                ("lookup", t_stacked, t_looked, did),
+                ("put", t_looked, fr.t_put, did),
+                ("inflight", fr.t_launched, fr.t_wait, fid),
+                ("wait", fr.t_wait, fr.t_ready, fid),
+                ("fetch", fr.t_ready, fr.t_retire, fid),
+                ("unpack", fr.t_retire, fr.t_done, rid)):
+            tr.complete(name, ts=ts, end=end, cat="flush", track="flushes",
+                        parent=parent)
+        tr.complete("launch", ts=fr.t_put, end=fr.t_launched, cat="flush",
+                    track="flushes", parent=did, executor=exec_label,
+                    batch=flush.padded_batch, n_shards=flush.n_shards)
         labels = (op, bucket, backend, exec_label)
-        self._m_wait.labels(*labels).observe(t_retire - t_wait, now=t_retire)
+        self._m_wait.labels(*labels).observe(fr.wait_s, now=fr.t_retire)
         lat = self._m_latency.labels(*labels)
         qwait = self._m_queue.labels(*labels)
         slo = obs.slo

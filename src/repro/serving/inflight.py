@@ -12,8 +12,9 @@ serving engine mirrors that with a three-stage software pipeline:
              handles.  ``ready()`` is the completion detector (no host
              block); the bound (``PCAServer(max_inflight=...)``) is the
              back-pressure valve that keeps memory and queueing honest.
-  retire     force one flush's results to host (a single gather), unpack
-             them into tickets, record telemetry.
+  retire     block until the device is done, force one flush's results
+             to host (a single gather), unpack them into tickets, record
+             telemetry.
 
 ``InFlightFlush`` is created by an executor (``sharded.LocalExecutor
 .submit`` / ``MeshExecutor.submit``) around the raw device output tree;
@@ -54,8 +55,9 @@ class InFlightFlush:
     buffers are gathered to host exactly once (``result``), then released.
     """
 
-    __slots__ = ("seq", "key", "entries", "t_dispatch", "t_launched",
-                 "backend", "batch_size", "padded_batch", "cache_hit",
+    __slots__ = ("seq", "key", "entries", "t_dispatch", "t_put",
+                 "t_launched", "stack_s", "lookup_s", "backend",
+                 "batch_size", "padded_batch", "cache_hit",
                  "inflight_depth", "n_shards", "retired", "span_id",
                  "_out", "_host", "_retire_cb")
 
@@ -69,7 +71,10 @@ class InFlightFlush:
         self.key: Optional[Tuple] = None
         self.entries: Tuple = ()
         self.t_dispatch = 0.0      # dispatch stage began (pre-stack)
-        self.t_launched = 0.0      # executor.submit returned (host free)
+        self.t_put = 0.0           # slab handed to the device (executor)
+        self.t_launched = 0.0      # executable call returned (host free)
+        self.stack_s = 0.0         # host time stacking and padding
+        self.lookup_s = 0.0        # host time finding the executable
         self.backend: Optional[str] = None
         self.batch_size = 0
         self.padded_batch = 0      # device batch after padding/rounding

@@ -30,6 +30,7 @@ compatibility path -- exactly ``submit(...).result()``.
 """
 from __future__ import annotations
 
+import time
 from typing import Callable, Optional, Sequence, Tuple
 
 import numpy as np
@@ -38,6 +39,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 
 from repro.core.pca import PCAConfig
+from repro.obs.tracing import stage
 from repro.parallel.sharding import (batch_axes, pad_to_multiple,
                                      rules_for_mesh)
 from .cache import SolverKey
@@ -79,9 +81,6 @@ class LocalExecutor:
     """
 
     n_shards: int = 1
-    # optional repro.obs.Observability bundle; the engine attaches its own
-    # when it carries one, so launches are traced where they happen
-    obs = None
 
     def cache_token(self):
         """Executor identity mixed into the engine's executable-cache key."""
@@ -123,7 +122,9 @@ class LocalExecutor:
         return self.compile(op, config, bucket, batch).lower(
             *solver_structs(bucket, batch)).compile()
 
-    def submit(self, fn: Callable, batch, n_active) -> InFlightFlush:
+    def submit(self, fn: Callable, batch, n_active,
+               clock: Callable[[], float] = time.monotonic,
+               start: Optional[float] = None) -> InFlightFlush:
         """Launch a flush without blocking (the pipeline's dispatch stage).
 
         JAX async dispatch returns the output tree as device futures, so
@@ -134,21 +135,18 @@ class LocalExecutor:
         is O(batch) dispatches, and on a sharded array each one is a
         cross-device gather that costs more than the flush's compute
         (measured ~3x the solve time at 8 host devices).
+
+        The copy in (``put``) and the call (``launch``) are timed as two
+        stages on ``clock``, from ``start`` when the caller has the stamp;
+        the handle carries their ends as ``t_put`` and ``t_launched``.
         """
-        obs = self.obs
-        if obs is None:
-            out = fn(jnp.asarray(batch), *map(jnp.asarray, n_active))
-        else:
-            t0 = obs.clock()
-            out = fn(jnp.asarray(batch), *map(jnp.asarray, n_active))
-            obs.tracer.complete(
-                "launch", ts=t0, end=obs.clock(), cat="launch",
-                track="launch", executor=self.describe(),
-                batch=int(np.shape(batch)[0]), n_shards=self.n_shards)
-            obs.metrics.counter(
-                "serve_launches_total", "Device launches by executor.",
-                ("executor", )).labels(self.describe()).inc()
-        return InFlightFlush(out, n_shards=self.n_shards)
+        with stage("put", clock, start) as put:
+            args = (jnp.asarray(batch), *map(jnp.asarray, n_active))
+        with stage("launch", clock, put.end) as launch:
+            out = fn(*args)
+        flush = InFlightFlush(out, n_shards=self.n_shards)
+        flush.t_put, flush.t_launched = put.end, launch.end
+        return flush
 
     def run(self, fn: Callable, batch, n_active):
         """Blocking compatibility path: ``submit(...).result()``."""

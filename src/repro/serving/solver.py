@@ -177,12 +177,13 @@ def jacobi_svd_batched(
     n_cols = _as_n_active(n_cols, B, nb)
     mm = matmul_fn or functools.partial(
         jnp.matmul, precision=matmul_precision(precision))
-    if fused:
-        from repro.kernels import ops as kops
-        gram = jax.vmap(lambda a: kops.covariance(
-            a, precision=precision, backend=fused_backend))(A)
-    else:
-        gram = jax.vmap(lambda a: mm(a.T, a))(A)
+    with jax.named_scope("covariance"):
+        if fused:
+            from repro.kernels import ops as kops
+            gram = jax.vmap(lambda a: kops.covariance(
+                a, precision=precision, backend=fused_backend))(A)
+        else:
+            gram = jax.vmap(lambda a: mm(a.T, a))(A)
     res = jacobi_eigh_batched(gram, n_active=n_cols, matmul_fn=matmul_fn,
                               fused=fused, fused_backend=fused_backend,
                               **eigh_kwargs)
@@ -249,12 +250,13 @@ def pca_fit_batched(
         Xs = X
         mean = jnp.zeros((B, db), X.dtype)
         scale = jnp.ones((B, db), X.dtype)
-    if config.fused:
-        from repro.kernels import ops as kops
-        C = jax.vmap(lambda x: kops.covariance(
-            x, precision=config.precision, backend=config.backend))(Xs)
-    else:
-        C = jax.vmap(lambda x: mm(x.T, x))(Xs)
+    with jax.named_scope("covariance"):
+        if config.fused:
+            from repro.kernels import ops as kops
+            C = jax.vmap(lambda x: kops.covariance(
+                x, precision=config.precision, backend=config.backend))(Xs)
+        else:
+            C = jax.vmap(lambda x: mm(x.T, x))(Xs)
     res = jacobi_eigh_batched(
         C, n_active=n_cols, sweeps=config.sweeps, pivot=config.pivot,
         rotation=config.rotation, angle=config.angle,
@@ -272,20 +274,28 @@ def build_solver_fn(op: str, config: PCAConfig) -> Callable:
     ops (eigh ignores the redundant column counts: the two n_active axes of a
     square bucket coincide), so the serving executors can jit it with
     whatever device placement they own -- plain ``jax.jit`` on the default
-    executor, batch-axis ``NamedSharding``s on the mesh executor.
+    executor, batch-axis ``NamedSharding``s on the mesh executor.  The
+    function is named ``<op>_solve``, so its executable reads
+    ``jit_<op>_solve`` in a device trace.
     """
     kw = dict(sweeps=config.sweeps, pivot=config.pivot,
               rotation=config.rotation, angle=config.angle, tol=config.tol,
               matmul_fn=config.matmul_fn(),
               fused=config.fused, fused_backend=config.backend)
-    if op == "eigh":
-        return lambda C, nr, nc: jacobi_eigh_batched(C, nr, **kw)
-    if op == "svd":
-        return lambda A, nr, nc: jacobi_svd_batched(
-            A, nr, nc, precision=config.precision, **kw)
-    if op == "pca":
-        return lambda X, nr, nc: pca_fit_batched(X, nr, nc, config=config)
-    raise ValueError(f"unknown op {op!r}")
+    def eigh_solve(C, nr, nc):
+        return jacobi_eigh_batched(C, nr, **kw)
+
+    def svd_solve(A, nr, nc):
+        return jacobi_svd_batched(A, nr, nc, precision=config.precision,
+                                  **kw)
+
+    def pca_solve(X, nr, nc):
+        return pca_fit_batched(X, nr, nc, config=config)
+
+    solvers = {"eigh": eigh_solve, "svd": svd_solve, "pca": pca_solve}
+    if op not in solvers:
+        raise ValueError(f"unknown op {op!r}")
+    return solvers[op]
 
 
 def pca_transform_batched(X, result: BatchedPCAResult, k: int,

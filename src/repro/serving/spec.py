@@ -12,7 +12,7 @@ equal specs are built from identical parts:
                    the threshold router's cut-over), solver numerics.
   CacheSpec        the persistent executable tier + warmup profile.
   ObsSpec          tracing/metrics/SLO outputs (obs is armed iff any
-                   output is requested).
+                   of them is requested; a ``jax_profile`` alone is not).
   ControllerSpec   the autonomous serving controller's cadence,
                    hysteresis and search budget.
 
@@ -91,7 +91,10 @@ class CacheSpec:
 
 @dataclasses.dataclass(frozen=True)
 class ObsSpec:
-    """Observability outputs; the bundle is armed iff any is set."""
+    """Observability outputs; the bundle is armed iff the SLO, trace or
+    metrics output is set.  ``jax_profile`` alone leaves it off: a
+    profiled serve runs the same uninstrumented path users run, and the
+    engine's ``serve.*`` stage spans reach the profile anyway."""
     slo_ms: Optional[float] = None
     trace_out: Optional[str] = None
     metrics_out: Optional[str] = None
@@ -100,7 +103,7 @@ class ObsSpec:
     @property
     def armed(self) -> bool:
         return any((self.slo_ms is not None, self.trace_out,
-                    self.metrics_out, self.jax_profile))
+                    self.metrics_out))
 
 
 @dataclasses.dataclass(frozen=True)

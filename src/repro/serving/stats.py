@@ -60,18 +60,25 @@ class RequestRecord:
 class FlushRecord:
     """Per-flush pipeline accounting (the dispatch/retire split).
 
-    ``t_dispatch`` is when the dispatch stage began (pre-stack),
-    ``t_launched`` when the non-blocking launch returned (host free again),
-    ``t_wait`` when the engine finally blocked on the flush, ``t_retire``
-    when its results were on host.  Of the in-flight window
-    [t_launched, t_retire], the part up to ``t_wait`` is device execution
-    the host *overlapped* with other work (batching or retiring
-    neighbours) and the rest is the un-hidden remainder; the flush's own
-    dispatch-stage host cost (``dispatch_s``) precedes the window.  A
-    synchronous engine (max_inflight=1) blocks immediately after
-    launching, so overlap_s ~ 0; a deep pipeline pushes overlap_frac
-    toward 1 -- that is the measured host/device overlap the benchmark
-    reports.
+    Stamps on the server's clock, in order: ``t_dispatch`` the dispatch
+    stage began (pre-stack), ``t_put`` the slab and its true sizes were
+    handed to the device, ``t_launched`` the non-blocking launch returned
+    (host free again), ``t_wait`` the engine blocked on the flush,
+    ``t_ready`` the device result was ready, ``t_retire`` the result was
+    on the host, ``t_done`` every ticket of the flush was fulfilled.
+    ``stack_s`` (stack the requests and pad the batch) and ``lookup_s``
+    (find or build the executable) are the stages between ``t_dispatch``
+    and ``t_put``, so the dispatch stage adds up as
+
+        dispatch_s = stack_s + lookup_s + put_s + launch_s
+
+    and the retire as ``wait_s + fetch_s + unpack_s``.  Of the in-flight
+    window [t_launched, t_retire], the part up to ``t_wait`` is device
+    execution the host *overlapped* with other work (batching or retiring
+    neighbours) and the rest is the un-hidden remainder.  A synchronous
+    engine (max_inflight=1) blocks immediately after launching, so
+    overlap_s ~ 0; a deep pipeline pushes overlap_frac toward 1 -- that is
+    the measured host/device overlap the benchmark reports.
     """
     t_dispatch: float
     t_launched: float
@@ -85,6 +92,12 @@ class FlushRecord:
     padded_batch: int = 0      # device batch after padding/rounding (the
                                # slab the executable actually consumed;
                                # padded_batch - batch_size is inert filler)
+    _: dataclasses.KW_ONLY
+    t_put: float
+    t_ready: float
+    t_done: float
+    stack_s: float
+    lookup_s: float
 
     @property
     def dispatch_s(self) -> float:
@@ -92,12 +105,38 @@ class FlushRecord:
         return self.t_launched - self.t_dispatch
 
     @property
+    def put_s(self) -> float:
+        """Host time handing the slab and true sizes to the device."""
+        return self.t_put - self.t_dispatch - self.stack_s - self.lookup_s
+
+    @property
+    def launch_s(self) -> float:
+        """Host time in the executable call (an XLA compile on a miss)."""
+        return self.t_launched - self.t_put
+
+    @property
     def overlap_s(self) -> float:
         return self.t_wait - self.t_launched
 
     @property
     def wait_s(self) -> float:
-        return self.t_retire - self.t_wait
+        """Time blocked on the device, excluding the copy home."""
+        return self.t_ready - self.t_wait
+
+    @property
+    def fetch_s(self) -> float:
+        """The single device-to-host gather of the flush's results."""
+        return self.t_retire - self.t_ready
+
+    @property
+    def unpack_s(self) -> float:
+        """Per-ticket unpacking, request records and fulfilment."""
+        return self.t_done - self.t_retire
+
+    @property
+    def inflight_s(self) -> float:
+        """The in-flight window, launch to results on the host."""
+        return self.t_retire - self.t_launched
 
 
 def percentile(xs: Sequence[float], p: float) -> float:
@@ -145,28 +184,41 @@ class ServingStats:
 
     def record_flush(self, cache_hit: bool, *,
                      t_dispatch: Optional[float] = None,
+                     t_put: Optional[float] = None,
                      t_launched: Optional[float] = None,
                      t_wait: Optional[float] = None,
+                     t_ready: Optional[float] = None,
                      t_retire: Optional[float] = None,
+                     t_done: Optional[float] = None,
+                     stack_s: float = 0.0,
+                     lookup_s: float = 0.0,
                      batch_size: int = 0,
                      inflight_depth: int = 1,
                      op: str = "",
                      bucket: Tuple[int, ...] = (),
-                     padded_batch: int = 0) -> None:
+                     padded_batch: int = 0) -> Optional[FlushRecord]:
+        """Count one flush; with ``t_dispatch`` also keep its record
+        (returned), each missing later stamp taken as the one before."""
         self.flushes += 1
         if cache_hit:
             self.cache_hits += 1
         else:
             self.cache_misses += 1
-        if t_dispatch is not None:
-            self.flush_records.append(FlushRecord(
-                t_dispatch=t_dispatch,
-                t_launched=t_dispatch if t_launched is None else t_launched,
-                t_wait=t_dispatch if t_wait is None else t_wait,
-                t_retire=t_dispatch if t_retire is None else t_retire,
-                batch_size=batch_size, cache_hit=cache_hit,
-                inflight_depth=inflight_depth, op=op, bucket=tuple(bucket),
-                padded_batch=padded_batch))
+        if t_dispatch is None:
+            return None
+        stamps, t = [], t_dispatch
+        for given in (t_put, t_launched, t_wait, t_ready, t_retire, t_done):
+            t = t if given is None else given
+            stamps.append(t)
+        t_put, t_launched, t_wait, t_ready, t_retire, t_done = stamps
+        rec = FlushRecord(
+            t_dispatch=t_dispatch, t_launched=t_launched, t_wait=t_wait,
+            t_retire=t_retire, batch_size=batch_size, cache_hit=cache_hit,
+            inflight_depth=inflight_depth, op=op, bucket=tuple(bucket),
+            padded_batch=padded_batch, t_put=t_put, t_ready=t_ready,
+            t_done=t_done, stack_s=stack_s, lookup_s=lookup_s)
+        self.flush_records.append(rec)
+        return rec
 
     def record_plan_switch(self, switch: Dict,
                            now: Optional[float] = None) -> None:
@@ -205,7 +257,7 @@ class ServingStats:
         # spend doing other work (batching / retiring neighbours) rather
         # than blocked waiting
         overlap_s = float(sum(f.overlap_s for f in self.flush_records))
-        span_s = overlap_s + float(sum(f.wait_s for f in self.flush_records))
+        span_s = float(sum(f.inflight_s for f in self.flush_records))
         deadline_misses = sum(1 for r in self.records if r.deadline_missed)
         return {
             "requests": len(self.records),

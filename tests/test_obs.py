@@ -428,7 +428,11 @@ def test_serving_metrics_and_backend_collector():
     assert 'serve_request_latency_seconds_bucket{op="eigh",bucket="8x8"' \
         in prom or 'serve_request_latency_seconds_bucket{op="eigh"' in prom
     assert "serve_flushes_total" in prom and "cache=" in prom
-    assert "serve_launches_total" in prom
+    # one launch per flush, recorded as the launch child of its dispatch
+    spans = {s.id: s for s in obs.tracer.spans}
+    launches = [s for s in spans.values() if s.name == "launch"]
+    assert len(launches) == srv.stats.flushes
+    assert all(spans[s.parent].name == "dispatch" for s in launches)
     # the kernel registry's resolution counts surface at export time:
     # force a resolution so the collector has something to mirror (the
     # plain-XLA datapath this config serves on never calls resolve())

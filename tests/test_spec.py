@@ -227,6 +227,15 @@ def test_build_server_arms_obs_and_controller_only_when_asked():
     assert steered.controller.window_s == 1.0
 
 
+def test_jax_profile_alone_does_not_arm_obs():
+    """A profiled serve runs the uninstrumented path users run; only the
+    SLO, trace and metrics outputs arm the bundle."""
+    obs = ObsSpec(jax_profile="/profile/dir")
+    assert not obs.armed
+    assert build_server(ServerSpec(obs=obs)).obs is None
+    assert ObsSpec(jax_profile="/profile/dir", trace_out="t.json").armed
+
+
 def test_build_server_injects_shared_clock():
     t = [7.0]
     srv = build_server(ServerSpec(obs=ObsSpec(slo_ms=100.0)),
