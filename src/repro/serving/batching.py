@@ -100,6 +100,105 @@ def stack_requests(mats: Sequence[np.ndarray], bucket: Sequence[int]):
     return batch, n_active
 
 
+def _zero_outside(slot: np.ndarray, old: Tuple[int, ...],
+                  new: Tuple[int, ...]) -> None:
+    """Zero the part of the box ``[0, old)`` of ``slot`` that lies outside
+    the box ``[0, new)``: one slice per axis where ``old`` reaches past
+    ``new``, the slices disjoint (axes before it clipped to both boxes)."""
+    for ax in range(len(old)):
+        if old[ax] > new[ax]:
+            idx = (tuple(slice(0, min(o, n))
+                         for o, n in zip(old[:ax], new[:ax]))
+                   + (slice(new[ax], old[ax]),)
+                   + tuple(slice(0, o) for o in old[ax + 1:]))
+            slot[idx] = 0
+
+
+class StagingSlab:
+    """One host batch slab, (bp, *bucket), zero outside its live data.
+
+    ``extents[i]`` is the shape the last occupant of slot ``i`` wrote
+    (``None``: the slot is all zero), so a refill zeroes only what the new
+    occupant does not overwrite.
+    """
+
+    __slots__ = ("key", "array", "extents")
+
+    def __init__(self, key: Tuple, bucket: Tuple[int, ...], bp: int, dtype):
+        self.key = key
+        self.array = np.zeros((bp, *bucket), dtype)
+        self.extents: list = [None] * bp
+
+    def fill(self, mats: Sequence[np.ndarray]) -> None:
+        """Write ``mats`` into the leading slots; the rest become filler."""
+        bucket = self.array.shape[1:]
+        if len(mats) > len(self.extents):
+            raise ValueError(f"{len(mats)} matrices for a slab of "
+                             f"{len(self.extents)} slots")
+        for m in mats:
+            if m.ndim != len(bucket) or any(
+                    d > b for d, b in zip(m.shape, bucket)):
+                raise ValueError(
+                    f"matrix shape {m.shape} does not fit bucket {bucket}")
+        for i, extent in enumerate(self.extents):
+            slot = self.array[i]
+            new = mats[i].shape if i < len(mats) else None
+            if new is not None:
+                slot[tuple(slice(0, d) for d in new)] = mats[i]
+            if extent is not None:
+                _zero_outside(slot, extent, new or (0,) * slot.ndim)
+            self.extents[i] = new
+
+
+class StagingPool:
+    """Reused, already-zeroed host slabs for the dispatch stage.
+
+    A padded slab built afresh for every flush is allocated, faulted in
+    and written whole; for a 70000x784 request at a batch of 4 that is
+    about 1.1 GB, nearly all of it zeros the previous flush had already
+    written.  The pool keeps slabs of (bucket, padded batch, dtype) and
+    writes each flush's requests into a free one of its key in place; its
+    bytes equal ``stack_requests`` plus zero filler, the reference the
+    tests hold it to.
+
+    A slab is taken at dispatch and given back (``release``) once its
+    flush has retired, never while a flush that read it is in flight: the
+    host-to-device copy may still run after the put returns, and on the
+    CPU the device array may alias the host buffer.  So the slab itself
+    must never be donated to an executable; only the device copy made
+    from it may be (``sharded._donate_kwargs``).
+
+    At most ``max_free`` slabs are kept free, over all keys together, the
+    least recently released dropped first; the server passes its
+    ``max_inflight``, so traffic that moves between buckets keeps no more
+    host slabs than one bucket would.
+    """
+
+    def __init__(self):
+        self._free: list = []      # least recently released first
+
+    def take(self, mats: Sequence[np.ndarray], bucket: Sequence[int],
+             bp: int) -> Tuple[StagingSlab, bool]:
+        """A slab holding ``mats`` in its first slots and zero filler up to
+        ``bp``, and whether it was reused (False: freshly allocated)."""
+        bucket = tuple(int(d) for d in bucket)
+        key = (bucket, bp, np.result_type(*mats))
+        for i in range(len(self._free) - 1, -1, -1):
+            if self._free[i].key == key:
+                slab, reused = self._free.pop(i), True
+                break
+        else:
+            slab, reused = StagingSlab(key, bucket, bp, key[2]), False
+        slab.fill(mats)
+        return slab, reused
+
+    def release(self, slab: StagingSlab, max_free: int) -> None:
+        """Give back the slab of a retired flush, keeping the ``max_free``
+        most recently released slabs."""
+        self._free.append(slab)
+        del self._free[:-max_free]
+
+
 def padding_waste(shape: Sequence[int], bucket: Sequence[int]) -> float:
     """Fraction of the bucket area occupied by padding (0 = exact fit)."""
     true = float(np.prod([int(d) for d in shape]))

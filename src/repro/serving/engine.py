@@ -13,15 +13,18 @@ executables and never recompiles.
 A flush is a three-stage pipeline, the software image of the paper's
 block-streaming (keep the S arrays busy while the next block streams in):
 
-  dispatch   ``_dispatch_key``: stack/pad, grab the cached executable,
-             launch via ``executor.submit`` -- non-blocking, the host goes
-             straight back to batching while the device crunches.
+  dispatch   ``_dispatch_key``: stage the requests into a kept, already
+             zeroed host slab (``batching.StagingPool``), grab the cached
+             executable, launch via ``executor.submit`` -- non-blocking,
+             the host goes straight back to batching while the device
+             crunches.
   in-flight  a bounded ``inflight.InFlightQueue`` of launched flushes
              (``max_inflight`` is the back-pressure valve).
   retire     ``_retire``: block on the device, one host gather per flush,
-             unpack into tickets, record telemetry.  ``poll``/``drain``
-             retire completed flushes; ``Ticket.result()``/
-             ``Ticket.wait()`` force exactly their own flush home.
+             give the slab back, unpack into tickets, record telemetry.
+             ``poll``/``drain`` retire completed flushes;
+             ``Ticket.result()``/``Ticket.wait()`` force exactly their own
+             flush home.
 
 Each step of a flush runs inside ``repro.obs.tracing.stage``: ``stack``,
 ``lookup``, ``put`` and ``launch`` in dispatch, ``wait``, ``fetch`` and
@@ -54,7 +57,7 @@ import numpy as np
 
 from repro.core.pca import PCAConfig
 from repro.obs.tracing import stage
-from .batching import BucketPolicy, padding_waste, stack_requests
+from .batching import BucketPolicy, StagingPool, padding_waste
 from .cache import DEFAULT_MAX_ENTRIES, ExecutableCache, SolverKey
 from .inflight import InFlightFlush, InFlightQueue
 from .sharded import LocalExecutor
@@ -367,6 +370,7 @@ class PCAServer:
         self._inflight = InFlightQueue()
         self._cache = ExecutableCache(max_entries=max_cached_executables,
                                       cache_dir=cache_dir)
+        self._staging = StagingPool()
         self._rid = itertools.count()
         self._seq = itertools.count()
         self._exec_label = self.executor.describe()
@@ -429,6 +433,10 @@ class PCAServer:
         self._m_disk = m.counter(
             "serve_cache_disk_total",
             "Persistent executable-tier lookups by outcome.", ("event",))
+        self._m_slabs = m.counter(
+            "serve_stage_slabs_total",
+            "Host staging slabs taken by dispatch, reused or allocated.",
+            ("event",))
         self._m_warm = m.counter(
             "serve_warmup_executables_total",
             "Executables pre-built by warmup(), by cache source.",
@@ -655,19 +663,17 @@ class PCAServer:
         t_dispatch = clock()
         b = len(queue)
         with stage("stack", clock, t_dispatch) as stack:
-            batch, n_active = stack_requests([e.matrix for e in queue],
-                                             bucket)
             bp = max(self.max_batch if self.pad_batches else b, b)
             # the executor may demand a larger batch (a mesh pads up to the
             # next data-axis multiple so every shard gets an identical slab)
             bp = self.executor.round_batch(bp)
-            if bp > b:  # inert filler: zero matrices, zero live coordinates
-                batch = np.concatenate(
-                    [batch, np.zeros((bp - b, *bucket), batch.dtype)])
-                n_active = np.concatenate(
-                    [n_active,
-                     np.zeros((n_active.shape[0], bp - b), np.int32)],
-                    axis=1)
+            # a kept slab, zero outside its live data: slots past b are
+            # inert filler (zero matrices, zero live coordinates)
+            slab, reused = self._staging.take(
+                [e.matrix for e in queue], bucket, bp)
+            n_active = np.zeros((len(bucket), bp), np.int32)
+            n_active[:, :b] = np.asarray(
+                [e.matrix.shape for e in queue], np.int32).T
         backend = self.backend_for(op, bucket)
         with stage("lookup", clock, stack.end) as lookup:
             fn, source = self._executable(op, bucket, bp, backend, sweeps)
@@ -688,7 +694,7 @@ class PCAServer:
                     track="flushes", parent=flush_span, op=op,
                     bucket=list(bucket), batch=bp, backend=str(backend))
         hit = source != "compile"
-        flush = self.executor.submit(fn, batch, n_active, clock=clock,
+        flush = self.executor.submit(fn, slab.array, n_active, clock=clock,
                                      start=lookup.end)
         flush.seq = next(self._seq)
         flush.key = key
@@ -699,6 +705,8 @@ class PCAServer:
         flush.backend = backend
         flush.batch_size = b
         flush.padded_batch = bp
+        flush.slab = slab
+        flush.slab_reused = reused
         flush.cache_hit = hit
         flush._retire_cb = self._retire
         self._inflight.push(flush)
@@ -712,6 +720,8 @@ class PCAServer:
                 op, bucket, backend, self._exec_label,
                 "hit" if hit else "miss").inc(now=t_dispatch)
             self._m_batch.labels(op, bucket).observe(b, now=t_dispatch)
+            self._m_slabs.labels(
+                "reused" if reused else "allocated").inc(now=t_dispatch)
             self._m_depth.set(self._inflight.depth, now=t_dispatch)
             self._m_queued.set(self.pending(), now=t_dispatch)
         # back-pressure: block on the oldest flush until the cap holds.
@@ -740,6 +750,9 @@ class PCAServer:
         with stage("fetch", clock, wait.end) as fetch:
             out = flush.result()
         t_retire = fetch.end
+        # the outputs are home, so nothing reads the slab any more
+        self._staging.release(flush.slab, self.max_inflight)
+        flush.slab = None
         flush.retired = True
         self._inflight.remove(flush)
         records = []
@@ -766,7 +779,8 @@ class PCAServer:
             stack_s=flush.stack_s, lookup_s=flush.lookup_s,
             batch_size=flush.batch_size,
             inflight_depth=flush.inflight_depth,
-            op=op, bucket=bucket, padded_batch=flush.padded_batch)
+            op=op, bucket=bucket, padded_batch=flush.padded_batch,
+            slab_reused=flush.slab_reused)
         if self.obs is not None:
             self._record_obs(flush, records, fr)
         return len(flush.entries)

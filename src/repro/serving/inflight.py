@@ -57,9 +57,9 @@ class InFlightFlush:
 
     __slots__ = ("seq", "key", "entries", "t_dispatch", "t_put",
                  "t_launched", "stack_s", "lookup_s", "backend",
-                 "batch_size", "padded_batch", "cache_hit",
-                 "inflight_depth", "n_shards", "retired", "span_id",
-                 "_out", "_host", "_retire_cb")
+                 "batch_size", "padded_batch", "slab", "slab_reused",
+                 "cache_hit", "inflight_depth", "n_shards", "retired",
+                 "span_id", "_out", "_host", "_retire_cb")
 
     def __init__(self, out, n_shards: int = 1):
         self._out = out            # device result tree (async futures)
@@ -78,6 +78,8 @@ class InFlightFlush:
         self.backend: Optional[str] = None
         self.batch_size = 0
         self.padded_batch = 0      # device batch after padding/rounding
+        self.slab = None           # host staging slab (batching.StagingSlab)
+        self.slab_reused = False   # the slab was kept from an earlier flush
         self.cache_hit = False
         self.inflight_depth = 1
         self.span_id: Optional[int] = None  # reserved flush-span id (obs)

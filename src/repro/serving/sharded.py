@@ -61,11 +61,15 @@ def solver_structs(bucket: Tuple[int, ...],
 def _donate_kwargs() -> dict:
     """Donate the flush's input slab to its executable.
 
-    The engine never reuses a dispatched batch, so XLA may alias the input
-    buffer for outputs -- one less allocation per in-flight flush, which is
-    what keeps a deep pipeline's memory footprint flat on accelerators.
-    CPU PJRT cannot alias host buffers and logs a warning per compiled
-    executable, so donation is reserved for real device backends.
+    The donated buffer is the device copy ``submit`` makes of the host
+    slab, which no later flush reads, so XLA may alias it for outputs --
+    one less allocation per in-flight flush, which is what keeps a deep
+    pipeline's memory footprint flat on accelerators.  The host slab
+    itself is reused once its flush retires (``batching.StagingPool``),
+    so donation must never reach it: on the CPU the device array may
+    alias the host buffer, and CPU PJRT cannot alias host buffers anyway
+    (it logs a warning per compiled executable), so donation is reserved
+    for real device backends.
     """
     if jax.default_backend() == "cpu":
         return {}

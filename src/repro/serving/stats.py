@@ -92,6 +92,8 @@ class FlushRecord:
     padded_batch: int = 0      # device batch after padding/rounding (the
                                # slab the executable actually consumed;
                                # padded_batch - batch_size is inert filler)
+    slab_reused: bool = False  # staged into a host slab kept from an
+                               # earlier flush (False: freshly allocated)
     _: dataclasses.KW_ONLY
     t_put: float
     t_ready: float
@@ -196,7 +198,8 @@ class ServingStats:
                      inflight_depth: int = 1,
                      op: str = "",
                      bucket: Tuple[int, ...] = (),
-                     padded_batch: int = 0) -> Optional[FlushRecord]:
+                     padded_batch: int = 0,
+                     slab_reused: bool = False) -> Optional[FlushRecord]:
         """Count one flush; with ``t_dispatch`` also keep its record
         (returned), each missing later stamp taken as the one before."""
         self.flushes += 1
@@ -215,7 +218,8 @@ class ServingStats:
             t_dispatch=t_dispatch, t_launched=t_launched, t_wait=t_wait,
             t_retire=t_retire, batch_size=batch_size, cache_hit=cache_hit,
             inflight_depth=inflight_depth, op=op, bucket=tuple(bucket),
-            padded_batch=padded_batch, t_put=t_put, t_ready=t_ready,
+            padded_batch=padded_batch, slab_reused=slab_reused,
+            t_put=t_put, t_ready=t_ready,
             t_done=t_done, stack_s=stack_s, lookup_s=lookup_s)
         self.flush_records.append(rec)
         return rec
