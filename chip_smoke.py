@@ -8,7 +8,8 @@ One chip: ``PCAServer`` is built from ``ServerSpec`` defaults, as
 
   * one ``pca`` request of mnist-28x28 at its full 70000x784 and one
     ``svd`` request of 20-newsgroups at 18846x1024 (seeded synthetic data
-    of those shapes, ``benchmarks.common.synthetic_dataset``);
+    of those shapes, ``benchmarks.common.synthetic_dataset``), the svd
+    checked as a factorisation too: its residual and U's orthonormality;
   * a burst of small eigh/svd/pca requests at dims 8-64, replayed to show
     the steady state compiles nothing;
   * the mnist request again on the kernel datapath (``backend="pallas"``);
@@ -104,6 +105,22 @@ def check_values(label: str, got, ref, budget: float) -> float:
     return err
 
 
+def check_factors(label: str, X, res, budget: float) -> dict:
+    """The served thin SVD as a factorisation of X in float64: the
+    residual ||X - U S V^T|| / ||X|| within the svd budget, and U's
+    columns orthonormal to within n * eps_f32, where the solver's
+    refinement stops."""
+    a = np.asarray(X, np.float64)
+    U, S, Vt = (np.asarray(x, np.float64) for x in (res.U, res.S, res.Vt))
+    resid = float(np.linalg.norm(a - (U * S[None, :]) @ Vt)
+                  / np.linalg.norm(a))
+    orth = float(np.abs(U.T @ U - np.eye(U.shape[1])).max())
+    bound = U.shape[1] * float(np.finfo(np.float32).eps)
+    check(resid <= budget, f"{label}: residual {resid} > {budget}")
+    check(orth <= bound, f"{label}: max |U^T U - I| {orth} > {bound}")
+    return {"residual": resid, "u_orth_err": orth, "u_orth_bound": bound}
+
+
 def check_subspace(label: str, vecs, ref_w, ref_V, cov_budget: float
                    ) -> dict:
     """Top-k subspace (k at 95% CVCR) within the Davis-Kahan bound."""
@@ -163,6 +180,7 @@ def full_shape_phase(srv, data, refs, budgets) -> dict:
                 name, res.S, np.sqrt(np.maximum(ref_w, 0.0)), budgets["svd"])
             doc.update(check_subspace(name, np.asarray(res.Vt).T, ref_w,
                                       ref_V, budgets["covariance"]))
+            doc.update(check_factors(name, X, res, budgets["svd"]))
             doc["off_norm"] = "not served for svd"
         say(f"full-shape {name} (smoke timings)", **doc, **timing)
         fits[name] = res

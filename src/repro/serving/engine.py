@@ -441,6 +441,10 @@ class PCAServer:
             "serve_warmup_executables_total",
             "Executables pre-built by warmup(), by cache source.",
             ("source",))
+        self._m_dead = m.counter(
+            "serve_svd_dead_columns_total",
+            "Live svd columns of U zeroed by the solver's cutoff.",
+            ("bucket",))
 
     # -- request path -------------------------------------------------------
     def submit(self, matrix, op: str = "eigh",
@@ -756,6 +760,7 @@ class PCAServer:
         flush.retired = True
         self._inflight.remove(flush)
         records = []
+        answer_bytes = 0
         with stage("unpack", clock, t_retire) as unpack:
             for i, e in enumerate(flush.entries):
                 rec = RequestRecord(
@@ -768,8 +773,11 @@ class PCAServer:
                     t_dispatch=flush.t_dispatch,
                     inflight_depth=flush.inflight_depth,
                     deadline=e.flush_by, sweeps=sweeps)
-                e.ticket._fulfil(self._unpack(op, out, i, e.matrix.shape),
-                                 rec)
+                answer = self._unpack(op, out, i, e.matrix.shape)
+                answer_bytes += sum(
+                    v.nbytes for v in vars(answer).values()
+                    if isinstance(v, np.ndarray))
+                e.ticket._fulfil(answer, rec)
                 self.stats.record_request(rec)
                 records.append(rec)
         fr = self.stats.record_flush(
@@ -780,13 +788,14 @@ class PCAServer:
             batch_size=flush.batch_size,
             inflight_depth=flush.inflight_depth,
             op=op, bucket=bucket, padded_batch=flush.padded_batch,
-            slab_reused=flush.slab_reused)
+            slab_reused=flush.slab_reused,
+            fetched_bytes=flush.fetched_bytes, answer_bytes=answer_bytes)
         if self.obs is not None:
-            self._record_obs(flush, records, fr)
+            self._record_obs(flush, records, fr, out)
         return len(flush.entries)
 
     def _record_obs(self, flush: InFlightFlush, records: List[RequestRecord],
-                    fr: FlushRecord) -> None:
+                    fr: FlushRecord, out) -> None:
         """Emit the retired flush's spans and metrics (obs attached only).
 
         One flush span (dispatch -> retire-complete) with dispatch /
@@ -794,7 +803,8 @@ class PCAServer:
         children broken into their stages, all from the flush record's
         stamps; then one request span per fulfilled ticket, parented to
         the flush span -- the link that ties a request's latency to the
-        microbatch that actually served it.
+        microbatch that actually served it.  An svd flush also counts the
+        live columns its solver zeroed (``out.n_dead``, host copy).
         """
         obs = self.obs
         tr = obs.tracer
@@ -834,6 +844,9 @@ class PCAServer:
                     batch=flush.padded_batch, n_shards=flush.n_shards)
         labels = (op, bucket, backend, exec_label)
         self._m_wait.labels(*labels).observe(fr.wait_s, now=fr.t_retire)
+        if op == "svd":
+            self._m_dead.labels(bucket).inc(
+                int(np.sum(out.n_dead[:len(records)])), now=fr.t_retire)
         lat = self._m_latency.labels(*labels)
         qwait = self._m_queue.labels(*labels)
         slo = obs.slo

@@ -59,7 +59,7 @@ class InFlightFlush:
                  "t_launched", "stack_s", "lookup_s", "backend",
                  "batch_size", "padded_batch", "slab", "slab_reused",
                  "cache_hit", "inflight_depth", "n_shards", "retired",
-                 "span_id", "_out", "_host", "_retire_cb")
+                 "span_id", "fetched_bytes", "_out", "_host", "_retire_cb")
 
     def __init__(self, out, n_shards: int = 1):
         self._out = out            # device result tree (async futures)
@@ -83,6 +83,7 @@ class InFlightFlush:
         self.cache_hit = False
         self.inflight_depth = 1
         self.span_id: Optional[int] = None  # reserved flush-span id (obs)
+        self.fetched_bytes = 0     # bytes of the gathered result tree
         self._retire_cb: Optional[Callable] = None
 
     def ready(self) -> bool:
@@ -108,6 +109,8 @@ class InFlightFlush:
         if self._host is None:
             self._host = jax.tree.map(np.asarray, self._out)
             self._out = None       # release the device buffers
+            self.fetched_bytes = sum(
+                leaf.nbytes for leaf in jax.tree.leaves(self._host))
         return self._host
 
     def retire(self) -> int:

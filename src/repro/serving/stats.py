@@ -72,7 +72,10 @@ class FlushRecord:
 
         dispatch_s = stack_s + lookup_s + put_s + launch_s
 
-    and the retire as ``wait_s + fetch_s + unpack_s``.  Of the in-flight
+    and the retire as ``wait_s + fetch_s + unpack_s``.  ``fetched_bytes``
+    is the size of the result tree the fetch gathered home (every slot of
+    the padded batch, filler included) and ``answer_bytes`` that of the
+    true-shape slices the flush's tickets keep.  Of the in-flight
     window [t_launched, t_retire], the part up to ``t_wait`` is device
     execution the host *overlapped* with other work (batching or retiring
     neighbours) and the rest is the un-hidden remainder.  A synchronous
@@ -100,6 +103,8 @@ class FlushRecord:
     t_done: float
     stack_s: float
     lookup_s: float
+    fetched_bytes: int = 0
+    answer_bytes: int = 0
 
     @property
     def dispatch_s(self) -> float:
@@ -199,7 +204,9 @@ class ServingStats:
                      op: str = "",
                      bucket: Tuple[int, ...] = (),
                      padded_batch: int = 0,
-                     slab_reused: bool = False) -> Optional[FlushRecord]:
+                     slab_reused: bool = False,
+                     fetched_bytes: int = 0,
+                     answer_bytes: int = 0) -> Optional[FlushRecord]:
         """Count one flush; with ``t_dispatch`` also keep its record
         (returned), each missing later stamp taken as the one before."""
         self.flushes += 1
@@ -220,7 +227,8 @@ class ServingStats:
             inflight_depth=inflight_depth, op=op, bucket=tuple(bucket),
             padded_batch=padded_batch, slab_reused=slab_reused,
             t_put=t_put, t_ready=t_ready,
-            t_done=t_done, stack_s=stack_s, lookup_s=lookup_s)
+            t_done=t_done, stack_s=stack_s, lookup_s=lookup_s,
+            fetched_bytes=fetched_bytes, answer_bytes=answer_bytes)
         self.flush_records.append(rec)
         return rec
 

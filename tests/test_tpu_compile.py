@@ -73,6 +73,18 @@ def test_default_pca_solver_fits_one_v5e(one_chip):
     assert 70000 * 784 * 4 <= used < V5E_HBM_BYTES, used
 
 
+def test_default_svd_solver_fits_one_v5e(one_chip):
+    """The served svd at 20-newsgroups' full 18846x1024 (bucket 18848):
+    the Gram path, the back-projection and the refinement in one
+    executable within one chip's memory."""
+    fn = build_solver_fn("svd", PCAConfig(T=16, S=1, sweeps=12))
+    compiled = _compile(fn, *_solver_structs(one_chip, (18848, 1024)))
+    mem = compiled.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes)
+    assert 2 * 18848 * 1024 * 4 <= used < V5E_HBM_BYTES, used
+
+
 def test_pallas_eigh_runs_the_mm_engine(one_chip):
     """eigh on the kernel backend with the unified MM-Engine rotation
     datapath: the Jacobi rotations lower to the Pallas matmul."""
