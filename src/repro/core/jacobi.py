@@ -25,9 +25,16 @@ Two rotation-application modes:
 Convergence: fixed deterministic sweep count (default 50, the paper's safety
 schedule) with optional software early-exit tolerance.
 
-Name scopes ``jacobi_sweep``, ``jacobi_round`` and ``jacobi_rotation``
-mark one sweep, one pivot round and the rotation apply in the ``op_name``
-of the compiled operations, so a device trace can be reduced by stage.
+Parallel pivots with row/column rotations (unfused) run on the Brent-Luk
+tournament layout: C as four blocks and V transposed, in the slots of
+``tournament_slots``, so each round rotates top slot i against bottom slot
+i elementwise and then moves the players by a static shift.  Every other
+combination indexes its pivots' rows and columns.
+
+Name scopes ``jacobi_sweep``, ``jacobi_round``, ``jacobi_rotation`` and
+``jacobi_shift`` mark one sweep, one pivot round, the rotation apply and
+the tournament's move between rounds in the ``op_name`` of the compiled
+operations, so a device trace can be reduced by stage.
 """
 from __future__ import annotations
 
@@ -81,6 +88,27 @@ def round_robin_rounds(n: int) -> np.ndarray:
             pairs.append((min(a, b), max(a, b)))
         rounds.append(pairs)
         players = [players[0]] + [players[-1]] + players[1:-1]
+    return np.asarray(rounds, dtype=np.int32)
+
+
+@functools.lru_cache(maxsize=64)
+def tournament_slots(n: int) -> np.ndarray:
+    """(n-1, 2, n//2) players in the top and bottom slots of each round.
+
+    The circle method of ``round_robin_rounds`` held as two rows of slots,
+    ``top = players[:n/2]`` and ``bot = players[n-1:n/2-1:-1]``, so that
+    round r pairs top slot i with bottom slot i: the same unordered pairs,
+    round by round.  Between rounds ``_shift`` moves the players; after
+    n-1 moves they are back in their starting slots.
+    """
+    assert n % 2 == 0, "round-robin ordering needs even n (pad first)"
+    h = n // 2
+    top, bot = list(range(h)), list(range(n - 1, h - 1, -1))
+    rounds = []
+    for _ in range(n - 1):
+        rounds.append((top, bot))
+        if n > 2:
+            top, bot = [top[0], bot[0]] + top[1:-1], bot[1:] + [top[-1]]
     return np.asarray(rounds, dtype=np.int32)
 
 
@@ -157,6 +185,138 @@ def _apply_rotations_matmul(C, V, p, q, c, s, matmul_fn):
     C = matmul_fn(matmul_fn(J.T, C), J)
     V = matmul_fn(V, J)
     return C, V
+
+
+def _diagonal(X):
+    """Diagonals of the trailing square axes, gathered by no index: a masked
+    sum whose fill and start are -0.0, so each sum returns its one entry
+    exactly, the sign of a zero included."""
+    n = X.shape[-1]
+    neg0 = np.array(-0.0, X.dtype)
+    masked = jnp.where(jnp.eye(n, dtype=bool), X, neg0)
+    return lax.reduce(masked, neg0, lax.add, (X.ndim - 1,))
+
+
+def _rotate_pairs(top, bot, c, s, top_is_p):
+    """``_apply_rotations_rowcol``'s update of each slot pair, written from
+    the side of p: p' = c p - s q, q' = s p + c q.
+
+    Each output is one sum of two products whose first product is the
+    indexed round's first, picked by ``where`` from the slot holding p, so
+    a compiler that fuses a product into the sum (an FMA) meets the same
+    expression as on the indexed path (writing q' = c q + s p, say, moved
+    the CPU results off the indexed ones in the last bit).
+    """
+    p = jnp.where(top_is_p, top, bot)
+    q = jnp.where(top_is_p, bot, top)
+    new_p = c * p + (-s) * q
+    new_q = s * p + c * q
+    return (jnp.where(top_is_p, new_p, new_q),
+            jnp.where(top_is_p, new_q, new_p))
+
+
+def _shift(top, bot, axis: int):
+    """The circle method's move between rounds along ``axis`` of the two rows
+    of slots: top' = [top0, bot0, top1..top(h-2)], bot' = [bot1..bot(h-1),
+    top(h-1)].  Each output selects, slot by slot, from its inputs moved by
+    one slot (a static pad), so the move fuses with what produced them; at
+    h = 1 nothing moves."""
+    h = top.shape[axis]
+    if h == 1:
+        return top, bot
+
+    def moved(x, by):
+        cfg = [(0, 0, 0)] * x.ndim
+        cfg[axis] = (by, -by, 0)
+        return lax.pad(x, np.array(0, x.dtype), cfg)
+
+    slot = lax.broadcasted_iota(jnp.int32, top.shape, axis)
+    down_top, down_bot = moved(top, 1), moved(bot, 1)
+    new_top = jnp.where(slot == 0, top,
+                        jnp.where(slot == 1, down_bot, down_top))
+    new_bot = jnp.where(slot == h - 1, top, moved(bot, -1))
+    return new_top, new_bot
+
+
+def _enter_tournament(C, V):
+    """C and V in the starting slots of ``tournament_slots``: top slot i holds
+    coordinate i, bottom slot i holds n-1-i.
+
+    C becomes its four (n/2, n/2) blocks, ``Cb[a, b]`` = the rows of half a
+    against the columns of half b, so a pair's two rows (and two columns)
+    sit at the same offset of two blocks; V is held transposed, ``Vt[a, i]``
+    = the column of V in slot i of half a, so its rotation acts on rows.
+    """
+    h = C.shape[0] // 2
+
+    def halves(x, axis):
+        cut = functools.partial(lax.slice_in_dim, x, axis=axis)
+        return cut(0, h), jnp.flip(cut(h, 2 * h), axis)
+
+    top, bot = halves(C, 0)
+    Cb = jnp.stack([jnp.stack(halves(top, 1)), jnp.stack(halves(bot, 1))])
+    return Cb, jnp.stack(halves(V.T, 0))
+
+
+def _leave_tournament(Cb, Vt):
+    """Inverse of ``_enter_tournament``: C and V in natural order."""
+    def join(a, b, axis):
+        return jnp.concatenate([a, jnp.flip(b, axis)], axis)
+
+    C = join(join(Cb[0, 0], Cb[0, 1], 1), join(Cb[1, 0], Cb[1, 1], 1), 0)
+    return C, join(Vt[0], Vt[1], 0).T
+
+
+@jax.named_scope("jacobi_rotation")
+def _rotate_tournament(Cb, Vt, c, s, top_is_p):
+    """Rotate every top slot against its bottom slot: C's rows, then its
+    columns, then V's columns -- elementwise, c and s broadcast per slot."""
+    col = (c[:, None], s[:, None], top_is_p[:, None])
+    rows = jnp.stack(_rotate_pairs(Cb[0], Cb[1], *col))
+    Cb = jnp.stack(_rotate_pairs(rows[:, 0], rows[:, 1], c, s, top_is_p),
+                   axis=1)
+    return Cb, jnp.stack(_rotate_pairs(Vt[0], Vt[1], *col))
+
+
+def _tournament_sweep(Cb, Vt, angle_fn):
+    """One parallel sweep on the tournament layout (``_enter_tournament``).
+
+    Round r rotates top slot i against bottom slot i, whose players are
+    ``tournament_slots(n)[r]``: the pairs of ``round_robin_rounds(n)[r]``.
+    Each pair keeps p = min, q = max: the angle reads (apq, app, aqq) from
+    the block diagonals that hold C[p, q], C[p, p] and C[q, q], and
+    ``_rotate_pairs`` writes the update from the side of whichever slot
+    holds p.  So every entry is computed by the indexed round's expression,
+    bit for bit, with no gather or scatter; then ``_shift`` moves the
+    players (``jacobi_shift``).  The block diagonals are read at the end of
+    the round that wrote the blocks and carried to the next: on a TPU the
+    compiler then keeps the whole loop's C and V in VMEM.
+    """
+    h = Cb.shape[-1]
+    slots = jnp.asarray(tournament_slots(2 * h))
+
+    @jax.named_scope("jacobi_round")
+    def body(carry, players):
+        Cb, Vt, d = carry
+        top, bot = players[0], players[1]
+        top_is_p = top < bot
+        apq = jnp.where(top_is_p, d[0, 1], d[1, 0])
+        app = jnp.where(top_is_p, d[0, 0], d[1, 1])
+        aqq = jnp.where(top_is_p, d[1, 1], d[0, 0])
+        _, c, s = angle_fn(apq, app, aqq)
+        c, s = _null_pivot_guard(jnp.minimum(top, bot), jnp.maximum(top, bot),
+                                 apq, c, s)
+        c = c.astype(Cb.dtype)
+        s = s.astype(Cb.dtype)
+        Cb, Vt = _rotate_tournament(Cb, Vt, c, s, top_is_p)
+        with jax.named_scope("jacobi_shift"):
+            Cb = jnp.stack(_shift(Cb[0], Cb[1], 1))
+            Cb = jnp.stack(_shift(Cb[:, 0], Cb[:, 1], 2), axis=1)
+            Vt = jnp.stack(_shift(Vt[0], Vt[1], 0))
+        return (Cb, Vt, _diagonal(Cb)), None
+
+    (Cb, Vt, _), _ = lax.scan(body, (Cb, Vt, _diagonal(Cb)), slots)
+    return Cb, Vt
 
 
 def _sweep_scan(C, V, rounds, angle_fn, rotation, matmul_fn,
@@ -283,6 +443,14 @@ def jacobi_eigh(
     n = C.shape[0]
     V = jnp.eye(n, dtype=C.dtype)
 
+    # parallel pivots rotated row/column-wise run on the tournament layout,
+    # entered once here and left once after the last sweep
+    tournament = pivot == "parallel" and rotation == "rowcol" and not fused
+    state = _enter_tournament(C, V) if tournament else (C, V)
+
+    def natural(state):
+        return _leave_tournament(*state) if tournament else state
+
     if pivot == "parallel":
         rounds = jnp.asarray(round_robin_rounds(n))
         rot_per_sweep = None
@@ -294,7 +462,10 @@ def jacobi_eigh(
         rot_per_sweep = (n_in * (n_in - 1)) // 2  # one "sweep" worth
 
     @jax.named_scope("jacobi_sweep")
-    def one_sweep(C, V):
+    def one_sweep(state):
+        if tournament:
+            return _tournament_sweep(*state, angle_fn)
+        C, V = state
         if pivot == "paper":
             return _max_pivot_sweep(C, V, rot_per_sweep, angle_fn, rotation,
                                     matmul_fn)
@@ -303,35 +474,33 @@ def jacobi_eigh(
                            fused_backend=fused_backend)
 
     if tol is not None:
-        def cond(state):
-            i, C, V = state
-            return (i < sweeps) & (relative_offdiag(C) > tol)
+        def cond(carry):
+            i, state = carry
+            return (i < sweeps) & (relative_offdiag(natural(state)[0]) > tol)
 
-        def body(state):
-            i, C, V = state
-            C, V = one_sweep(C, V)
-            return i + 1, C, V
+        def body(carry):
+            i, state = carry
+            return i + 1, one_sweep(state)
 
-        _, C, V = lax.while_loop(cond, body, (jnp.int32(0), C, V))
+        _, state = lax.while_loop(cond, body, (jnp.int32(0), state))
         history = None
     elif track_history:
         hist0 = relative_offdiag(C)
 
-        def body(carry, _):
-            C, V = carry
-            C, V = one_sweep(C, V)
-            return (C, V), relative_offdiag(C)
+        def body(state, _):
+            state = one_sweep(state)
+            return state, relative_offdiag(natural(state)[0])
 
-        (C, V), hist = lax.scan(body, (C, V), None, length=sweeps)
+        state, hist = lax.scan(body, state, None, length=sweeps)
         history = jnp.concatenate([hist0[None], hist])
     else:
-        def body(carry, _):
-            C, V = carry
-            return one_sweep(C, V), None
+        def body(state, _):
+            return one_sweep(state), None
 
-        (C, V), _ = lax.scan(body, (C, V), None, length=sweeps)
+        state, _ = lax.scan(body, state, None, length=sweeps)
         history = None
 
+    C, V = natural(state)
     off = relative_offdiag(C)
     eigvals = jnp.diagonal(C)
     if padded:

@@ -24,7 +24,8 @@ def covariance_gram(x, acc_dtype=jnp.float32, out_dtype=None):
 
 
 def jacobi_sweep_step(C, V, pairs, angle: str = "rutishauser"):
-    """One pivot round, unfused: the exact ``core.jacobi`` sweep body."""
+    """One pivot round, unfused, indexed by its pairs: the ``core.jacobi``
+    round that the tournament layout reproduces bit for bit."""
     from repro.core.cordic import ANGLE_MODES
     from repro.core.jacobi import _apply_rotations_rowcol, _null_pivot_guard
     p = pairs[:, 0]

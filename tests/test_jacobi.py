@@ -148,3 +148,143 @@ def test_property_offdiag_monotone_to_floor(n, seed):
     above = hist > 1e-6
     deltas = np.diff(hist)
     assert np.all(deltas[above[:-1]] < 1e-3)
+
+
+# ---------------------------------------------------------------------------
+# tournament layout: the parallel row/column round without gathers
+# ---------------------------------------------------------------------------
+
+ANGLES = ("rutishauser", "atan2", "cordic")
+
+
+def _tournament_sweep_fn(angle):
+    from repro.core.cordic import ANGLE_MODES
+    from repro.core.jacobi import (_enter_tournament, _leave_tournament,
+                                   _tournament_sweep)
+
+    def sweep(C, V):
+        state = _tournament_sweep(*_enter_tournament(C, V), ANGLE_MODES[angle])
+        return _leave_tournament(*state)
+    return sweep
+
+
+def _indexed_sweep_fn(angle):
+    """The indexed chain: ``kref.jacobi_sweep_step`` over every round."""
+    from repro.kernels import ref as kref
+
+    def sweep(C, V):
+        rounds = jnp.asarray(round_robin_rounds(C.shape[0]))
+        step = lambda cv, pairs: (kref.jacobi_sweep_step(*cv, pairs,
+                                                         angle=angle), None)
+        return jax.lax.scan(step, (C, V), rounds)[0]
+    return sweep
+
+
+def _padded_problem(n, batch=None, seed=0):
+    """Symmetric C zero-padded to even size (as ``jacobi_eigh`` pads odd n)
+    and V = I; with ``batch``, a stack of independent problems."""
+    m = n + n % 2
+    rng = np.random.default_rng(seed + n)
+    shape = (n, n) if batch is None else (batch, n, n)
+    a = rng.standard_normal(shape).astype(np.float32)
+    c = (a + np.swapaxes(a, -1, -2)) / 2
+    pad = [(0, 0)] * (c.ndim - 2) + [(0, m - n), (0, m - n)]
+    v = np.broadcast_to(np.eye(m, dtype=np.float32), c.shape[:-2] + (m, m))
+    return jnp.asarray(np.pad(c, pad)), jnp.asarray(v)
+
+
+@pytest.mark.parametrize("n", [2, 4, 8, 10, 64, 784])
+def test_tournament_slots_and_shift_follow_the_circle_method(n):
+    """Slot i of the top row meets slot i of the bottom row: round by round
+    the pairs of ``round_robin_rounds``; ``_shift`` moves the players from
+    one round's slots to the next and, after n-1 moves, back to the start."""
+    from repro.core.jacobi import _shift, tournament_slots
+    slots = tournament_slots(n)
+    assert slots.shape == (n - 1, 2, n // 2)
+    pairs = np.sort(np.swapaxes(slots, 1, 2), axis=-1)
+    np.testing.assert_array_equal(pairs, round_robin_rounds(n))
+    top, bot = jnp.asarray(slots[0, 0]), jnp.asarray(slots[0, 1])
+    for r in range(1, n):
+        top, bot = _shift(top, bot, 0)
+        expect = slots[r % (n - 1)]
+        np.testing.assert_array_equal(np.asarray(top), expect[0])
+        np.testing.assert_array_equal(np.asarray(bot), expect[1])
+
+
+@pytest.mark.parametrize("batched", [False, True], ids=["one", "vmap"])
+@pytest.mark.parametrize("angle", ANGLES)
+@pytest.mark.parametrize("n", [2, 8, 9, 10, 64])
+def test_tournament_sweep_equals_indexed_chain(n, angle, batched):
+    """A full sweep on the tournament layout computes every entry of C and V
+    as the indexed round does: bit for bit at fp32 on the CPU.
+
+    Under ``vmap`` (a batch of four, as served) one exception: the CPU
+    compiler fuses ``tau * tau`` into ``1 + tau * tau`` (an FMA) in the
+    vectorised part of a loop and not in its remainder, and which lanes
+    fall where follows the batch's memory order, which differs between the
+    two programs; the indexed chain compiled with and without ``vmap``
+    differs in the same way.  So the Rutishauser angle's vmapped sweep is
+    held to rounding, 8 ulps of the largest entry; the other angle modes
+    stay bitwise there too.
+    """
+    new = _tournament_sweep_fn(angle)
+    ref = _indexed_sweep_fn(angle)
+    if batched:
+        new, ref = jax.vmap(new), jax.vmap(ref)
+    C, V = _padded_problem(n, batch=4 if batched else None)
+    got = jax.jit(new)(C, V)
+    want = jax.jit(ref)(C, V)
+    for g, w in zip(got, want):
+        if batched and angle == "rutishauser":
+            ulp = float(np.finfo(np.float32).eps) * float(jnp.max(jnp.abs(w)))
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=0,
+                                       atol=8 * ulp)
+        else:
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+@pytest.mark.parametrize("angle", ANGLES)
+def test_tournament_sweep_keeps_bucket_padding_exact(angle):
+    """A 7x7 problem zero-padded into a 12 bucket: through three sweeps the
+    padded rows and columns of C stay exactly 0, and V's padded block
+    exactly I with nothing mixed into the live block."""
+    n, nb = 7, 12
+    c, _ = _padded_problem(n)
+    C = jnp.zeros((nb, nb), jnp.float32).at[:8, :8].set(c)
+    V = jnp.eye(nb, dtype=jnp.float32)
+    sweep = _tournament_sweep_fn(angle)
+    C, V = jax.jit(lambda C, V: sweep(*sweep(*sweep(C, V))))(C, V)
+    C, V = np.asarray(C), np.asarray(V)
+    assert not np.any(C[n:, :]) and not np.any(C[:, n:])
+    np.testing.assert_array_equal(V[n:, n:], np.eye(nb - n))
+    assert not np.any(V[n:, :n]) and not np.any(V[:n, n:])
+    assert np.any(C[:n, :n] != np.diag(np.diagonal(C[:n, :n])))  # live rotated
+
+
+def test_served_round_holds_no_gather_or_scatter():
+    """The default served ``pca_solve`` executable rotates on the tournament
+    layout: no ``gather`` or ``scatter`` in the Jacobi loops' bodies (the
+    masked sort after them may gather), and the ``jacobi_shift`` scope in
+    their operations' metadata."""
+    import re
+    from repro.core import PCAConfig
+    from repro.serving.solver import build_solver_fn
+    fn = build_solver_fn("pca", PCAConfig(sweeps=2))
+    x = jax.ShapeDtypeStruct((4, 96, 64), jnp.float32)
+    i = jax.ShapeDtypeStruct((4,), jnp.int32)
+    hlo = jax.jit(fn).lower(x, i, i).compile().as_text()
+    comps = dict(re.findall(r"^(?:ENTRY )?%?([\w.\-]+) [^\n]*\{\n(.*?)\n\}",
+                            hlo, re.S | re.M))
+    called = re.compile(r"(?:calls|body|condition|to_apply)=%?([\w.\-]+)")
+    todo = re.findall(r"\bwhile\([^\n]*body=%?([\w.\-]+)", hlo)
+    assert todo, "no while loop in the solver"
+    seen = set()
+    while todo:
+        name = todo.pop()
+        if name in seen:
+            continue
+        seen.add(name)
+        todo += called.findall(comps[name])
+    loops = "\n".join(comps[name] for name in seen)
+    assert not re.search(r"\b(gather|scatter)\(", loops)
+    assert "/jacobi_shift/" in loops
