@@ -10,6 +10,7 @@ subprocess oracle, reported next to the documented ``ERROR_BUDGETS``
 ceiling each must stay under."""
 from __future__ import annotations
 
+import jax.numpy as jnp
 import numpy as np
 
 from repro.core.schedule import (convergence_curve, make_ill_conditioned,
@@ -23,7 +24,7 @@ def precision_axis(fast: bool = True):
     One small dataset (the oracle pays a subprocess + x64 solve per op);
     the budgets are ceilings, the emitted numbers the measured truth."""
     from repro.core import precision as prec
-    from repro.kernels import ops as kops
+    from repro.kernels import ref as kref
     from repro.core.jacobi import jacobi_eigh
 
     x = synthetic_dataset(512, 24, 9)
@@ -31,8 +32,10 @@ def precision_axis(fast: bool = True):
     oracle_c = prec.run_fp64_oracle(x, "covariance")
     oracle_e = prec.run_fp64_oracle(x, "eigh", sweeps=sweeps)
     for precision in ("fp32", "bf16_fp32acc"):
-        C = kops.covariance(x, block_m=64, precision=precision,
-                            backend="interpret")
+        # the bf16-operand, fp32-accumulate Gram
+        C = kref.covariance_gram(
+            jnp.asarray(x).astype(prec.operand_dtype(precision)),
+            acc_dtype=prec.acc_dtype(precision))
         err_c = prec.rel_frobenius(np.asarray(C), oracle_c["C"])
         res = jacobi_eigh(np.asarray(C), sweeps=sweeps)
         err_e = prec.rel_frobenius(np.asarray(res.eigenvalues),

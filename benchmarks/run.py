@@ -7,9 +7,6 @@ Prints ``name,us_per_call,derived`` CSV.
   fig8  -- Frobenius-norm convergence study (paper Fig. 8 / Sec. VII-D)
   dse   -- T/S design-space exploration (paper Figs. 9-11)
   table3-- resource/config comparison (paper Tables I-III)
-  roofline -- analytic (arch x shape) terms from the dry-run records,
-              plus measured achieved-vs-peak FLOPs per (op, backend,
-              precision, fused/unfused) -> BENCH_roofline.json
   serve -- batched multi-tenant serving throughput (repro.serving)
   autotune -- tuned-vs-default serving-plan gain (serving.autotune)
   cold_start -- fresh-replica TTFR: cold JIT vs warm disk cache vs warmup
@@ -32,7 +29,7 @@ def main() -> None:
 
     from . import (autotune_gain, cold_start, controller_regret, dse,
                    fig1_bottlenecks, fig6_exec_time, fig7_energy,
-                   fig8_frobenius, goodput, perf_variants, roofline,
+                   fig8_frobenius, goodput, perf_variants,
                    serve_throughput, table3_configs)
     suite = {
         "table3": table3_configs,
@@ -41,7 +38,6 @@ def main() -> None:
         "fig6": fig6_exec_time,
         "fig1": fig1_bottlenecks,
         "dse": dse,
-        "roofline": roofline,
         "perf": perf_variants,
         "serve": serve_throughput,
         "autotune": autotune_gain,

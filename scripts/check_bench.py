@@ -66,7 +66,6 @@ REPO_ROOT = pathlib.Path(__file__).resolve().parent.parent
 METRIC_PREFERENCE = (
     ("requests_per_s", True),
     ("goodput_rps", True),
-    ("achieved_flops", True),
     ("us_per_request", False),
     ("ttfr_ms", False),
     ("mm_engine_us", False),
@@ -260,90 +259,6 @@ def goodput_gate(name: str, doc: dict, tol: float) -> tuple[list, bool]:
     return [header] + lines, ok
 
 
-def roofline_gate(name: str, doc: dict, tol: float) -> tuple[list, bool]:
-    """Intra-file invariants for BENCH_roofline.json, the fused-kernel
-    perf contract (ISSUE 9 acceptance):
-
-      fusion   on the large fp32 covariance bucket, every fused row must
-               beat the unfused block-streamed baseline by >= 1.15x
-               device time -- a fused kernel that stops out-running the
-               launch-per-block scan has lost its reason to exist.
-      bf16     where the platform natively supports bf16 operand
-               streaming (``bf16_supported`` -- TPU), the bf16 fused row
-               must reach >= 1.3x the fp32 fused row's achieved FLOPs on
-               the same (backend, bucket).  Rows measured on hosts that
-               emulate bf16 (CPU) carry ``bf16_supported: false`` and are
-               skipped with a note, never silently.
-
-    The tolerance is multiplicative slack on both floors."""
-    rows = [r for _, r in iter_rows(doc)
-            if r.get("op") == "covariance"
-            and isinstance(r.get("us_per_call"), (int, float))]
-    lines, ok, checked = [], True, 0
-
-    large = [r for r in rows
-             if r.get("bucket") == "large" and r.get("precision") == "fp32"]
-    unfused = {r.get("backend"): float(r["us_per_call"]) for r in large
-               if r.get("variant") == "unfused"}
-    if unfused:
-        floor = 1.15 * (1.0 - tol)
-        for r in large:
-            if r.get("variant") != "fused":
-                continue
-            # same-backend baseline (what that server config runs without
-            # fusion); kernel-less backends fall back to the plain-XLA scan
-            backend = r.get("backend")
-            base_us = unfused.get(backend, unfused.get("xla"))
-            if base_us is None:
-                continue
-            checked += 1
-            speedup = base_us / float(r["us_per_call"])
-            verdict = "ok"
-            if speedup < floor:
-                verdict, ok = "FUSION-LOST", False
-            lines.append(
-                f"  {verdict:<13} fused[{backend}] "
-                f"{float(r['us_per_call']):.0f}us vs unfused "
-                f"{base_us:.0f}us ({speedup:.2f}x, floor {floor:.2f}x)")
-    else:
-        lines.append("  no unfused large-bucket row; fusion gate skipped")
-
-    fused = {}
-    for r in rows:
-        if r.get("variant") == "fused":
-            fused[(r.get("backend"), r.get("bucket"),
-                   r.get("precision"))] = r
-    bf16_checked = 0
-    for (backend, bucket, precision), r in sorted(fused.items()):
-        if precision != "bf16_fp32acc":
-            continue
-        base_row = fused.get((backend, bucket, "fp32"))
-        if base_row is None:
-            continue
-        if not r.get("bf16_supported"):
-            lines.append(f"  skipped       bf16[{backend}/{bucket}] "
-                         f"(platform emulates bf16; no native win to hold)")
-            continue
-        checked += 1
-        bf16_checked += 1
-        floor = 1.3 * (1.0 - tol)
-        ratio = (float(r["achieved_flops"])
-                 / float(base_row["achieved_flops"]))
-        verdict = "ok"
-        if ratio < floor:
-            verdict, ok = "NO-BF16-WIN", False
-        lines.append(f"  {verdict:<13} bf16[{backend}/{bucket}] "
-                     f"{ratio:.2f}x fp32 achieved FLOPs "
-                     f"(floor {floor:.2f}x)")
-
-    if not checked and not lines:
-        return [f"{name}: no gateable rows; roofline gate skipped"], True
-    header = (f"{name}: roofline gate (fused >= 1.15x unfused on large "
-              f"fp32; bf16 >= 1.3x fp32 where native; "
-              f"{tol * 100:.0f}% slack)")
-    return [header] + lines, ok
-
-
 def controller_gate(name: str, doc: dict, tol: float) -> tuple[list, bool]:
     """Intra-file invariants for BENCH_controller_regret.json (the
     autonomous-controller acceptance, machine-independent: the regret
@@ -417,9 +332,6 @@ def compare_file(name: str, tol: float) -> tuple[list, bool]:
     elif name == "BENCH_goodput.json":
         extra_lines, extra_ok = goodput_gate(name, json.loads(fresh_text),
                                              tol)
-    elif name == "BENCH_roofline.json":
-        extra_lines, extra_ok = roofline_gate(name, json.loads(fresh_text),
-                                              tol)
     elif name == "BENCH_controller_regret.json":
         extra_lines, extra_ok = controller_gate(name,
                                                 json.loads(fresh_text), tol)

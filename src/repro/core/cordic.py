@@ -83,18 +83,12 @@ def _from_fixed(x):
     return x.astype(jnp.float32) / float(_ONE)
 
 
-def cordic_atan2(y, x, iters: int = CORDIC_ITERS, unroll: bool = False):
+def cordic_atan2(y, x, iters: int = CORDIC_ITERS):
     """Vectorised vectoring-mode CORDIC: atan2(y, x) for x of any sign.
 
     Inputs are floats; they are normalised into Q2.29 exactly as the RTL
     front-end scales operands into its fixed-point format (a shared scale
     leaves the angle unchanged).
-
-    ``unroll`` replaces the ``fori_loop`` over the angle table with an
-    unrolled loop whose per-stage constants are python ints -- required
-    inside a Pallas kernel body, which cannot capture a constant device
-    array.  The micro-rotations are pure int32 arithmetic, so both
-    spellings are bit-identical.
     """
     y = jnp.asarray(y, jnp.float32)
     x = jnp.asarray(x, jnp.float32)
@@ -110,26 +104,17 @@ def cordic_atan2(y, x, iters: int = CORDIC_ITERS, unroll: bool = False):
     xi = _to_fixed(xq)
     yi = _to_fixed(yq)
     zi = jnp.zeros_like(xi)
+    atan_tab = jnp.asarray(_ATAN_FIXED)
 
-    def body(i, carry, step):
+    def body(i, carry):
         xi, yi, zi = carry
         d = jnp.where(yi >= 0, 1, -1).astype(jnp.int32)
         x_new = xi + d * (yi >> i)
         y_new = yi - d * (xi >> i)
-        z_new = zi + d * step
+        z_new = zi + d * atan_tab[i]
         return x_new, y_new, z_new
 
-    if unroll:
-        carry = (xi, yi, zi)
-        for i in range(iters):
-            carry = body(i, carry, jnp.int32(int(_ATAN_FIXED[i])))
-        xi, yi, zi = carry
-    else:
-        # the table must only materialise on this branch: a constant device
-        # array would be captured by a Pallas kernel trace even when unused
-        atan_tab = jnp.asarray(_ATAN_FIXED)
-        xi, yi, zi = lax.fori_loop(
-            0, iters, lambda i, c: body(i, c, atan_tab[i]), (xi, yi, zi))
+    xi, yi, zi = lax.fori_loop(0, iters, body, (xi, yi, zi))
     ang = _from_fixed(zi)
     # unfold quadrant: atan2(y,x) = atan2(-y,-x) +/- pi
     pi = jnp.float32(np.pi)
@@ -137,10 +122,8 @@ def cordic_atan2(y, x, iters: int = CORDIC_ITERS, unroll: bool = False):
     return ang
 
 
-def cordic_sincos(theta, iters: int = CORDIC_ITERS, unroll: bool = False):
-    """Vectorised rotation-mode CORDIC: (sin, cos) of theta in (-pi, pi].
-
-    ``unroll`` as in ``cordic_atan2`` (Pallas-kernel-safe spelling)."""
+def cordic_sincos(theta, iters: int = CORDIC_ITERS):
+    """Vectorised rotation-mode CORDIC: (sin, cos) of theta in (-pi, pi]."""
     theta = jnp.asarray(theta, jnp.float32)
     half_pi = jnp.float32(np.pi / 2)
     # fold into (-pi/2, pi/2]; CORDIC rotation converges for |z| < ~1.74 rad
@@ -153,38 +136,29 @@ def cordic_sincos(theta, iters: int = CORDIC_ITERS, unroll: bool = False):
     zi = _to_fixed(th)
     xi = jnp.broadcast_to(_to_fixed(jnp.float32(1.0 / _GAIN)), zi.shape).astype(jnp.int32)
     yi = jnp.zeros_like(xi)
+    atan_tab = jnp.asarray(_ATAN_FIXED)
 
-    def body(i, carry, step):
+    def body(i, carry):
         xi, yi, zi = carry
         d = jnp.where(zi >= 0, 1, -1).astype(jnp.int32)
         x_new = xi - d * (yi >> i)
         y_new = yi + d * (xi >> i)
-        z_new = zi - d * step
+        z_new = zi - d * atan_tab[i]
         return x_new, y_new, z_new
 
-    if unroll:
-        carry = (xi, yi, zi)
-        for i in range(iters):
-            carry = body(i, carry, jnp.int32(int(_ATAN_FIXED[i])))
-        xi, yi, zi = carry
-    else:
-        # see cordic_atan2: keep the constant table off the unroll branch
-        atan_tab = jnp.asarray(_ATAN_FIXED)
-        xi, yi, zi = lax.fori_loop(
-            0, iters, lambda i, c: body(i, c, atan_tab[i]), (xi, yi, zi))
+    xi, yi, zi = lax.fori_loop(0, iters, body, (xi, yi, zi))
     sin = _from_fixed(yi)
     cos = _from_fixed(xi)
     sign = jnp.where(flip, -1.0, 1.0).astype(jnp.float32)
     return sin * sign, cos * sign
 
 
-def rotation_params_cordic(apq, app, aqq, iters: int = CORDIC_ITERS,
-                           unroll: bool = False):
+def rotation_params_cordic(apq, app, aqq, iters: int = CORDIC_ITERS):
     """Paper-faithful datapath: CORDIC atan -> 1-bit right shift -> CORDIC
     sin/cos (two rotators in parallel in the RTL; one fused call here)."""
-    full = cordic_atan2(2.0 * apq, app - aqq, iters, unroll=unroll)
+    full = cordic_atan2(2.0 * apq, app - aqq, iters)
     theta = -0.5 * full  # the RTL 1-bit arithmetic right shift (sign-fixed)
-    s, c = cordic_sincos(theta, iters, unroll=unroll)
+    s, c = cordic_sincos(theta, iters)
     return theta, c, s
 
 

@@ -52,26 +52,15 @@ def blocked_covariance(
     block_m: int = 128,
     matmul_fn: Optional[Callable] = None,
     normalize: bool = False,
-    fused: bool = False,
     precision: str = "fp32",
-    backend: Optional[str] = None,
 ) -> jnp.ndarray:
     """Stream sample blocks of T rows, accumulating partial products --
     the MM-Engine dataflow (matrix accumulators keep the output tile
     stationary while operand tiles stream through).
 
-    ``fused=True`` routes the whole accumulation through the one-launch
-    ``covariance`` registry op (paper Sec. VI-A fusion: one HBM pass, the
-    Gram accumulator stationary on-chip) instead of one matmul launch per
-    block; with fp32 ``precision`` the result is bitwise-identical to the
-    unfused path at the same ``block_m``.  ``precision`` selects the
-    operand-streaming dtype (``repro.core.precision``); ``backend`` names
-    the registry backend for the fused op.
+    ``precision`` names the matmul precision of the default datapath
+    (``repro.core.precision``); an injected ``matmul_fn`` sets its own.
     """
-    if fused:
-        from repro.kernels import ops as kops
-        return kops.covariance(X, block_m=block_m, precision=precision,
-                               normalize=normalize, backend=backend)
     mm = matmul_fn or functools.partial(
         jnp.matmul, precision=matmul_precision(precision))
     m, n = X.shape

@@ -25,7 +25,7 @@ Two rotation-application modes:
 Convergence: fixed deterministic sweep count (default 50, the paper's safety
 schedule) with optional software early-exit tolerance.
 
-Parallel pivots with row/column rotations (unfused) run on the Brent-Luk
+Parallel pivots with row/column rotations run on the Brent-Luk
 tournament layout: C as four blocks and V transposed, in the slots of
 ``tournament_slots``, so each round rotates top slot i against bottom slot
 i elementwise and then moves the players by a static shift.  Every other
@@ -319,46 +319,26 @@ def _tournament_sweep(Cb, Vt, angle_fn):
     return Cb, Vt
 
 
-def _sweep_scan(C, V, rounds, angle_fn, rotation, matmul_fn,
-                fused: bool = False, angle: str = "rutishauser",
-                fused_backend: Optional[str] = None):
-    """One full sweep: scan over pivot rounds.
+def _sweep_scan(C, V, rounds, angle_fn, rotation, matmul_fn):
+    """One full sweep: scan over pivot rounds, each indexed by its pairs."""
 
-    ``fused`` routes each round through the ``jacobi_sweep`` registry op --
-    gather + angle + null-pivot guard + row/col rotation in one kernel
-    launch (paper's fused Jacobian Unit) instead of a chain of XLA ops with
-    C and V round-tripping HBM between them.  The fused round is
-    bitwise-identical to the unfused body for every angle mode; it applies
-    to ``rotation="rowcol"`` only (the "matmul" datapath deliberately
-    routes rotations through the MM-Engine, so it stays unfused).
-    """
-    if fused and rotation == "rowcol":
-        from repro.kernels import ops as kops
-
-        @jax.named_scope("jacobi_round")
-        def body(carry, pairs):
-            C, V = carry
-            C, V = kops.jacobi_sweep(C, V, pairs, angle=angle,
-                                     backend=fused_backend)
-            return (C, V), None
-    else:
-        @jax.named_scope("jacobi_round")
-        def body(carry, pairs):
-            C, V = carry
-            p = pairs[:, 0]
-            q = pairs[:, 1]
-            apq = C[p, q]
-            app = C[p, p]
-            aqq = C[q, q]
-            _, c, s = angle_fn(apq, app, aqq)
-            c, s = _null_pivot_guard(p, q, apq, c, s)
-            c = c.astype(C.dtype)
-            s = s.astype(C.dtype)
-            if rotation == "rowcol":
-                C, V = _apply_rotations_rowcol(C, V, p, q, c, s)
-            else:
-                C, V = _apply_rotations_matmul(C, V, p, q, c, s, matmul_fn)
-            return (C, V), None
+    @jax.named_scope("jacobi_round")
+    def body(carry, pairs):
+        C, V = carry
+        p = pairs[:, 0]
+        q = pairs[:, 1]
+        apq = C[p, q]
+        app = C[p, p]
+        aqq = C[q, q]
+        _, c, s = angle_fn(apq, app, aqq)
+        c, s = _null_pivot_guard(p, q, apq, c, s)
+        c = c.astype(C.dtype)
+        s = s.astype(C.dtype)
+        if rotation == "rowcol":
+            C, V = _apply_rotations_rowcol(C, V, p, q, c, s)
+        else:
+            C, V = _apply_rotations_matmul(C, V, p, q, c, s, matmul_fn)
+        return (C, V), None
 
     (C, V), _ = lax.scan(body, (C, V), rounds)
     return C, V
@@ -397,8 +377,6 @@ def jacobi_eigh(
     tol: Optional[float] = None,
     track_history: bool = False,
     sort: bool = True,
-    fused: bool = False,
-    fused_backend: Optional[str] = None,
 ) -> EighResult:
     """Symmetric eigendecomposition via Jacobi rotations.
 
@@ -413,13 +391,6 @@ def jacobi_eigh(
       tol: optional early-exit relative off-diagonal tolerance. When set,
         a while_loop replaces the fixed schedule (software mode).
       track_history: record the relative off-norm after every sweep.
-      fused: run each pivot round through the fused ``jacobi_sweep``
-        registry op (one launch per round; bitwise-identical to the
-        unfused path).  Applies to the "parallel"/"cyclic" strategies with
-        rotation="rowcol"; "paper" (max-pivot DLE) and the "matmul"
-        rotation datapath fall back to the unfused chain.
-      fused_backend: registry backend for the fused op (None = resolution
-        order: pallas on TPU, interpret elsewhere).
     Returns:
       EighResult with eigenvalues (descending) and column eigenvectors.
     """
@@ -445,7 +416,7 @@ def jacobi_eigh(
 
     # parallel pivots rotated row/column-wise run on the tournament layout,
     # entered once here and left once after the last sweep
-    tournament = pivot == "parallel" and rotation == "rowcol" and not fused
+    tournament = pivot == "parallel" and rotation == "rowcol"
     state = _enter_tournament(C, V) if tournament else (C, V)
 
     def natural(state):
@@ -469,9 +440,7 @@ def jacobi_eigh(
         if pivot == "paper":
             return _max_pivot_sweep(C, V, rot_per_sweep, angle_fn, rotation,
                                     matmul_fn)
-        return _sweep_scan(C, V, rounds, angle_fn, rotation, matmul_fn,
-                           fused=fused, angle=angle,
-                           fused_backend=fused_backend)
+        return _sweep_scan(C, V, rounds, angle_fn, rotation, matmul_fn)
 
     if tol is not None:
         def cond(carry):
@@ -513,30 +482,18 @@ def jacobi_eigh(
     return EighResult(eigvals, V, off, history)
 
 
-def jacobi_svd(A, matmul_fn: Optional[Callable] = None,
-               fused: bool = False, fused_backend: Optional[str] = None,
-               precision: str = "fp32", **kwargs):
+def jacobi_svd(A, matmul_fn: Optional[Callable] = None, **kwargs):
     """SVD of A via eigendecomposition of the Gram matrix A^T A (the PCA
     path: singular values = sqrt(eigenvalues), V = right singular vectors).
     Returns (U, S, Vt) with the thin convention.
 
     The Gram product and the U = A V back-projection go through the same
     injected ``matmul_fn`` as the rotations: all three matmuls of the SVD
-    share the unified MM-Engine datapath (paper Sec. VI-A).  ``fused``
-    routes the Gram through the one-pass ``covariance`` registry op and the
-    sweeps through the fused ``jacobi_sweep`` op; ``precision`` selects the
-    Gram operand-streaming dtype (``repro.core.precision`` -- rotations and
-    the back-projection always stay fp32).
+    share the unified MM-Engine datapath (paper Sec. VI-A).
     """
     mm = matmul_fn or jnp.matmul
-    if fused:
-        from repro.kernels import ops as kops
-        gram = kops.covariance(A, precision=precision,
-                               backend=fused_backend)
-    else:
-        gram = mm(A.T, A)
-    res = jacobi_eigh(gram, matmul_fn=matmul_fn, fused=fused,
-                      fused_backend=fused_backend, **kwargs)
+    gram = mm(A.T, A)
+    res = jacobi_eigh(gram, matmul_fn=matmul_fn, **kwargs)
     s = jnp.sqrt(jnp.maximum(res.eigenvalues, 0.0))
     V = res.eigenvectors
     safe = jnp.maximum(s, 1e-30)

@@ -42,10 +42,6 @@ class PCAConfig:
     # "bf16_fp32acc" | "fp64"; see repro.core.precision).  Rotations,
     # angles and back-projections always stay fp32.
     precision: str = "fp32"
-    # route the hot path through the fused one-launch kernels (covariance
-    # + jacobi_sweep registry ops); bitwise-identical to the unfused path
-    # at fp32
-    fused: bool = False
 
     @property
     def use_pallas(self) -> bool:
@@ -97,8 +93,7 @@ def fit(X, config: PCAConfig = PCAConfig()) -> PCAResult:
         scale = jnp.ones((X.shape[1],), X.dtype)
     mm = config.matmul_fn()
     C = blocked_covariance(Xs, block_m=config.T, matmul_fn=mm,
-                           fused=config.fused, precision=config.precision,
-                           backend=config.backend)
+                           precision=config.precision)
     res: EighResult = jacobi_eigh(
         C,
         sweeps=config.sweeps,
@@ -107,8 +102,6 @@ def fit(X, config: PCAConfig = PCAConfig()) -> PCAResult:
         rotation=config.rotation,
         angle=config.angle,
         matmul_fn=mm,
-        fused=config.fused,
-        fused_backend=config.backend,
     )
     evcr, cvcr = evcr_cvcr(res.eigenvalues)
     return PCAResult(res.eigenvectors, res.eigenvalues, mean, scale, evcr,

@@ -5,8 +5,8 @@ is reduced-precision *operand streaming* with guarded accumulation (the
 standard throughput lever in the related FPGA-PCA literature -- Martel et
 al.'s hyperspectral PCA, Burrello et al.'s embedded PCA).  Three policies:
 
-  ``fp32``          fp32 operands, fp32 accumulation.  The default and the
-                    bitwise baseline every fused kernel is tested against.
+  ``fp32``          fp32 operands, fp32 accumulation, HIGHEST-precision
+                    matmuls.  The default.
   ``bf16_fp32acc``  bf16 operand streaming into fp32 accumulators for the
                     covariance/Gram products (half the HBM bytes on the
                     bandwidth-bound leg).  Jacobi rotations, angles and the

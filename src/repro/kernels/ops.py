@@ -27,7 +27,6 @@ from . import mm_engine as _mm
 from . import dle as _dle
 from . import cordic as _cordic
 from . import flash_attention as _fa
-from . import fused as _fused
 from . import mamba_scan as _ms
 from . import ref as _ref
 
@@ -79,102 +78,6 @@ def mm_engine_matmul(a, b, block: int = 128, *,
                      interpret: bool | None = None):
     """Block-streamed a @ b for arbitrary shapes (paper tile size T=block)."""
     return _mm_dispatch(a, b, block, _backend_name(backend, interpret))
-
-
-# -- covariance (fused one-pass Gram) ---------------------------------------
-
-def _cov_block_m(m: int, block_m: int) -> int:
-    """Effective streaming panel size: one sublane-aligned panel when the
-    matrix is shorter than the requested block (small serving buckets must
-    not pad up to a huge panel)."""
-    return min(block_m, -(-m // 8) * 8)
-
-
-def _cov_kernel_impl(x, *, block_m: int, precision: str, interpret: bool):
-    from repro.core import precision as prec
-    m, n = x.shape
-    bm = _cov_block_m(m, block_m)
-    xp = _pad_to(x, (bm, 1))  # zero sample rows add nothing to the Gram
-    xp = xp.astype(prec.operand_dtype(precision))
-    return _fused.fused_covariance(
-        xp, block_m=bm, acc_dtype=prec.acc_dtype(precision),
-        interpret=interpret)
-
-
-registry.register("covariance", "pallas")(
-    functools.partial(_cov_kernel_impl, interpret=False))
-registry.register("covariance", "interpret")(
-    functools.partial(_cov_kernel_impl, interpret=True))
-
-
-@registry.register("covariance", "ref")
-def _cov_ref_impl(x, *, block_m: int = 0, precision: str = "fp32"):
-    del block_m
-    from repro.core import precision as prec
-    xp = x.astype(prec.operand_dtype(precision))
-    return _ref.covariance_gram(xp, acc_dtype=prec.acc_dtype(precision))
-
-
-@functools.partial(jax.jit, static_argnames=("block_m", "precision",
-                                             "normalize", "backend"))
-def _cov_dispatch(x, block_m, precision, normalize, backend):
-    c = registry.resolve("covariance", backend)(x, block_m=block_m,
-                                                precision=precision)
-    if normalize:
-        c = c / jnp.maximum(x.shape[0] - 1, 1).astype(c.dtype)
-    return c
-
-
-def covariance(x, block_m: int = 1024, *, precision: str = "fp32",
-               normalize: bool = False, backend: str | None = None,
-               interpret: bool | None = None):
-    """Fused one-HBM-pass Gram matrix C = x^T x (paper Sec. VI-A fusion).
-
-    Sample panels of ``block_m`` rows stream through a single launch while
-    the full (n, n) accumulator stays stationary on-chip -- vs the unfused
-    ``core.covariance.blocked_covariance``, which launches one matmul per
-    panel and round-trips each partial C through HBM.  ``precision``
-    selects the operand streaming dtype (``repro.core.precision``);
-    accumulation never narrows below fp32.  With fp32 operands the result
-    is bitwise-identical to ``blocked_covariance`` at the same ``block_m``.
-    """
-    return _cov_dispatch(x, block_m, precision, normalize,
-                         _backend_name(backend, interpret))
-
-
-# -- jacobi_sweep (fused pivot round) ---------------------------------------
-
-def _sweep_kernel_impl(C, V, pairs, *, angle: str, interpret: bool):
-    return _fused.jacobi_sweep_step(C, V, pairs, angle=angle,
-                                    interpret=interpret)
-
-
-registry.register("jacobi_sweep", "pallas")(
-    functools.partial(_sweep_kernel_impl, interpret=False))
-registry.register("jacobi_sweep", "interpret")(
-    functools.partial(_sweep_kernel_impl, interpret=True))
-
-
-@registry.register("jacobi_sweep", "ref")
-def _sweep_ref_impl(C, V, pairs, *, angle: str = "rutishauser"):
-    return _ref.jacobi_sweep_step(C, V, pairs, angle=angle)
-
-
-@functools.partial(jax.jit, static_argnames=("angle", "backend"))
-def _sweep_dispatch(C, V, pairs, angle, backend):
-    return registry.resolve("jacobi_sweep", backend)(C, V, pairs,
-                                                     angle=angle)
-
-
-def jacobi_sweep(C, V, pairs, *, angle: str = "rutishauser",
-                 backend: str | None = None,
-                 interpret: bool | None = None):
-    """One fused Jacobi pivot round: gather + angle + guard + row/col
-    rotation over (C, V) in a single launch (paper's fused Jacobian Unit).
-    ``pairs`` is (k, 2) disjoint pivot indices.  Bitwise-identical to the
-    unfused ``core.jacobi._sweep_scan`` body for every angle mode."""
-    return _sweep_dispatch(C, V, pairs, angle,
-                           _backend_name(backend, interpret))
 
 
 # -- dle_find_pivot ---------------------------------------------------------

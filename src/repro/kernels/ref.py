@@ -1,4 +1,6 @@
-"""Pure-jnp oracles for every Pallas kernel (the allclose references)."""
+"""Pure-jnp oracles for every Pallas kernel (the allclose references), and
+the reference Gram and indexed Jacobi round the core paths are tested
+against."""
 from __future__ import annotations
 
 import jax
@@ -24,7 +26,7 @@ def covariance_gram(x, acc_dtype=jnp.float32, out_dtype=None):
 
 
 def jacobi_sweep_step(C, V, pairs, angle: str = "rutishauser"):
-    """One pivot round, unfused, indexed by its pairs: the ``core.jacobi``
+    """One pivot round, indexed by its pairs: the ``core.jacobi``
     round that the tournament layout reproduces bit for bit."""
     from repro.core.cordic import ANGLE_MODES
     from repro.core.jacobi import _apply_rotations_rowcol, _null_pivot_guard

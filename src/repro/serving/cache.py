@@ -52,8 +52,9 @@ import jax
 # old-format entries are simply never looked up again
 # (2: SolverKey grew precision + fused -- pre-mixed-precision executables
 # must never serve a precision-keyed request; 3: the record header is the
-# whole environment fingerprint, device kind included)
-CACHE_FORMAT = 3
+# whole environment fingerprint, device kind included; 4: SolverKey lost
+# fused with the fused kernel path)
+CACHE_FORMAT = 4
 
 # default in-memory cap: generous for steady traffic (a few ops x a few
 # buckets x a few batches), small enough that a plan-churning server stays
@@ -94,11 +95,10 @@ class SolverKey:
     standardize: bool
     backend: Optional[str]
     block: Optional[int]
-    # mixed-precision policy and fused-kernel routing both change the
-    # compiled executable (operand dtypes / kernel launch structure), so
-    # they are key material like the numerics above
+    # the mixed-precision policy changes the compiled executable (operand
+    # dtypes, matmul precision), so it is key material like the numerics
+    # above
     precision: str = "fp32"
-    fused: bool = False
 
     @classmethod
     def from_config(cls, config) -> "SolverKey":
@@ -107,8 +107,7 @@ class SolverKey:
             rotation=config.rotation, angle=config.angle,
             standardize=config.standardize, backend=config.backend,
             block=(config.T if config.backend is not None else None),
-            precision=getattr(config, "precision", "fp32"),
-            fused=getattr(config, "fused", False))
+            precision=getattr(config, "precision", "fp32"))
 
 
 def content_hash(op: str, bucket: Tuple[int, ...], batch: int,

@@ -114,8 +114,6 @@ def jacobi_eigh_batched(
     matmul_fn: Optional[Callable] = None,
     tol: Optional[float] = None,
     sort: bool = True,
-    fused: bool = False,
-    fused_backend: Optional[str] = None,
 ) -> BatchedEighResult:
     """Batched symmetric eigendecomposition over a shape bucket.
 
@@ -123,8 +121,7 @@ def jacobi_eigh_batched(
       C: (B, nb, nb) zero-padded symmetric matrices sharing one bucket.
       n_active: (B,) true sizes (None = all full).  Rows/cols >= n_active[i]
         must be zero; they provably never mix (null-pivot guard).
-      remaining args: as ``core.jacobi.jacobi_eigh`` (``fused`` vmaps the
-        one-launch-per-round ``jacobi_sweep`` kernel across the batch).
+      remaining args: as ``core.jacobi.jacobi_eigh``.
     """
     C = jnp.asarray(C)
     if C.ndim != 3:
@@ -134,8 +131,7 @@ def jacobi_eigh_batched(
     def solve(c):
         return jacobi_eigh(c, sweeps=sweeps, pivot=pivot, rotation=rotation,
                            angle=angle, matmul_fn=matmul_fn, tol=tol,
-                           sort=False, fused=fused,
-                           fused_backend=fused_backend)
+                           sort=False)
 
     res = jax.vmap(solve)(C)
     w, V = res.eigenvalues, res.eigenvectors
@@ -151,8 +147,6 @@ def jacobi_svd_batched(
     n_cols=None,
     matmul_fn: Optional[Callable] = None,
     rcond: Optional[float] = None,
-    fused: bool = False,
-    fused_backend: Optional[str] = None,
     precision: str = "fp32",
     sweeps: int = DEFAULT_SWEEPS,
     **eigh_kwargs,
@@ -215,15 +209,10 @@ def jacobi_svd_batched(
         jnp.matmul, precision=matmul_precision(precision))
 
     def gram(x):
-        if fused:
-            from repro.kernels import ops as kops
-            return jax.vmap(lambda a: kops.covariance(
-                a, precision=precision, backend=fused_backend))(x)
         return jax.vmap(lambda a: mm(a.T, a))(x)
 
     eigh = functools.partial(jacobi_eigh_batched, n_active=n_cols,
-                             matmul_fn=matmul_fn, fused=fused,
-                             fused_backend=fused_backend, **eigh_kwargs)
+                             matmul_fn=matmul_fn, **eigh_kwargs)
     with jax.named_scope("covariance"):
         G = gram(A)
     V = eigh(G, sweeps=sweeps).eigenvectors
@@ -332,17 +321,11 @@ def pca_fit_batched(
         mean = jnp.zeros((B, db), X.dtype)
         scale = jnp.ones((B, db), X.dtype)
     with jax.named_scope("covariance"):
-        if config.fused:
-            from repro.kernels import ops as kops
-            C = jax.vmap(lambda x: kops.covariance(
-                x, precision=config.precision, backend=config.backend))(Xs)
-        else:
-            C = jax.vmap(lambda x: mm(x.T, x))(Xs)
+        C = jax.vmap(lambda x: mm(x.T, x))(Xs)
     res = jacobi_eigh_batched(
         C, n_active=n_cols, sweeps=config.sweeps, pivot=config.pivot,
         rotation=config.rotation, angle=config.angle,
-        matmul_fn=config.matmul_fn(), tol=config.tol,
-        fused=config.fused, fused_backend=config.backend)
+        matmul_fn=config.matmul_fn(), tol=config.tol)
     evcr, cvcr = jax.vmap(evcr_cvcr)(res.eigenvalues)
     return BatchedPCAResult(res.eigenvectors, res.eigenvalues, mean, scale,
                             evcr, cvcr, res.off_norm, n_rows, n_cols)
@@ -361,8 +344,8 @@ def build_solver_fn(op: str, config: PCAConfig) -> Callable:
     """
     kw = dict(sweeps=config.sweeps, pivot=config.pivot,
               rotation=config.rotation, angle=config.angle, tol=config.tol,
-              matmul_fn=config.matmul_fn(),
-              fused=config.fused, fused_backend=config.backend)
+              matmul_fn=config.matmul_fn())
+
     def eigh_solve(C, nr, nc):
         return jacobi_eigh_batched(C, nr, **kw)
 

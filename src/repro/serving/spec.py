@@ -78,7 +78,6 @@ class ExecutionSpec:
     router_min_dim: Optional[int] = None  # threshold_router cut-over
     sweeps: int = 12
     precision: str = "fp32"
-    fused: bool = False
 
 
 @dataclasses.dataclass(frozen=True)
@@ -143,8 +142,7 @@ class ServerSpec:
                          S=self.scheduling.max_batch,
                          sweeps=self.execution.sweeps,
                          backend=self.execution.backend,
-                         precision=self.execution.precision,
-                         fused=self.execution.fused)
+                         precision=self.execution.precision)
 
     def validate(self) -> "ServerSpec":
         s = self.scheduling
@@ -176,6 +174,11 @@ class ServerSpec:
     def from_json(cls, text: str) -> "ServerSpec":
         doc = json.loads(text)
         doc.pop("server_spec", None)
+        # the fused one-launch kernels are gone: a spec that asked for
+        # them must not silently get the unfused path
+        if (doc.get("execution") or {}).get("fused"):
+            raise ValueError("execution.fused: the fused kernel path was "
+                             "removed; drop the key or set it to false")
         parts = {}
         for f in dataclasses.fields(cls):
             sub = doc.get(f.name)

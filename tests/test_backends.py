@@ -23,7 +23,7 @@ _AUTO = "pallas" if jax.default_backend() == "tpu" else "interpret"
 def test_registry_lists_every_op_and_backend():
     assert set(backends.registered_ops()) == {
         "mm_engine_matmul", "dle_find_pivot", "cordic_rotate",
-        "flash_attention", "mamba_scan", "covariance", "jacobi_sweep"}
+        "flash_attention", "mamba_scan"}
     for op in backends.registered_ops():
         assert backends.backends_for(op) == ("pallas", "interpret", "ref")
 
@@ -123,22 +123,6 @@ def _ms_inputs():
         dict(chunk=8)
 
 
-def _cov_inputs():
-    rng = np.random.default_rng(47)
-    x = jnp.asarray(rng.standard_normal((45, 18)), jnp.float32)
-    return (x,), dict(block_m=16)
-
-
-def _sweep_inputs():
-    rng = np.random.default_rng(48)
-    n = 12
-    a = rng.standard_normal((n, n)).astype(np.float32)
-    c = jnp.asarray((a + a.T) / 2)
-    v = jnp.eye(n, dtype=jnp.float32)
-    pairs = jnp.asarray([[0, 1], [2, 5], [4, 9], [6, 11]], jnp.int32)
-    return (c, v, pairs), {}
-
-
 # per-op (wrapper, inputs, tolerance): the CORDIC tolerance covers its
 # Q2.29 fixed-point angle quantisation vs the float-exact reference
 _PARITY_CASES = {
@@ -147,8 +131,6 @@ _PARITY_CASES = {
     "cordic_rotate": (ops.cordic_rotation_params, _cordic_inputs, 3e-7),
     "flash_attention": (ops.flash_attention, _fa_inputs, 2e-5),
     "mamba_scan": (ops.mamba_scan, _ms_inputs, 1e-4),
-    "covariance": (ops.covariance, _cov_inputs, 2e-5),
-    "jacobi_sweep": (ops.jacobi_sweep, _sweep_inputs, 0.0),
 }
 
 

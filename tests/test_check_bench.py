@@ -351,93 +351,6 @@ def test_emit_json_stamps_provenance(tmp_path, monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# the intra-file roofline gate (BENCH_roofline.json)
-# ---------------------------------------------------------------------------
-
-def _roofline_row(variant, backend, precision, us, bucket="large",
-                  bf16=False, gflops=None):
-    if gflops is None:
-        gflops = 1e6 / us          # any consistent flops/time stand-in
-    return {"op": "covariance", "bucket": bucket, "variant": variant,
-            "backend": backend, "precision": precision,
-            "bf16_supported": bf16, "us_per_call": us,
-            "achieved_flops": gflops * 1e9}
-
-
-def test_roofline_gate_fused_win_passes():
-    doc = _doc([
-        _roofline_row("unfused", "xla", "fp32", 8000.0),
-        _roofline_row("unfused", "interpret", "fp32", 30000.0),
-        _roofline_row("fused", "interpret", "fp32", 12000.0),
-        _roofline_row("fused", "ref", "fp32", 6000.0),
-    ])
-    lines, ok = check_bench.roofline_gate("r.json", doc, tol=0.25)
-    assert ok
-    assert sum(ln.strip().startswith("ok") for ln in lines) == 2
-
-
-def test_roofline_gate_pairs_fused_with_same_backend_baseline():
-    """The interpret fused row gates against the interpret unfused scan,
-    not the faster plain-XLA one; a kernel-less backend (ref) falls back
-    to the xla baseline."""
-    doc = _doc([
-        _roofline_row("unfused", "xla", "fp32", 5000.0),
-        _roofline_row("unfused", "interpret", "fp32", 30000.0),
-        # 12000us loses to xla (0.42x) but beats interpret (2.5x): ok
-        _roofline_row("fused", "interpret", "fp32", 12000.0),
-    ])
-    lines, ok = check_bench.roofline_gate("r.json", doc, tol=0.25)
-    assert ok
-
-
-def test_roofline_gate_fusion_lost_fails():
-    doc = _doc([
-        _roofline_row("unfused", "interpret", "fp32", 10000.0),
-        _roofline_row("fused", "interpret", "fp32", 15000.0),
-    ])
-    lines, ok = check_bench.roofline_gate("r.json", doc, tol=0.25)
-    assert not ok
-    assert any("FUSION-LOST" in ln for ln in lines)
-
-
-def test_roofline_gate_bf16_win_required_only_where_native():
-    base = [
-        _roofline_row("unfused", "interpret", "fp32", 30000.0),
-        _roofline_row("fused", "interpret", "fp32", 10000.0, gflops=50.0),
-    ]
-    # emulated bf16 (bf16_supported false): slower than fp32, still ok
-    doc = _doc(base + [_roofline_row("fused", "interpret", "bf16_fp32acc",
-                                     12000.0, gflops=40.0)])
-    lines, ok = check_bench.roofline_gate("r.json", doc, tol=0.25)
-    assert ok and any("skipped" in ln and "bf16" in ln for ln in lines)
-    # native bf16 must hold the 1.3x achieved-FLOPs floor (0.975x with
-    # the 25% slack -- bf16 merely *matching* fp32 within noise passes,
-    # clearly losing to it does not)
-    doc = _doc(base + [_roofline_row("fused", "interpret", "bf16_fp32acc",
-                                     12000.0, bf16=True, gflops=42.0)])
-    lines, ok = check_bench.roofline_gate("r.json", doc, tol=0.25)
-    assert not ok and any("NO-BF16-WIN" in ln for ln in lines)
-    doc = _doc(base + [_roofline_row("fused", "interpret", "bf16_fp32acc",
-                                     6000.0, bf16=True, gflops=85.0)])
-    lines, ok = check_bench.roofline_gate("r.json", doc, tol=0.25)
-    assert ok
-
-
-def test_roofline_gate_without_rows_skips():
-    lines, ok = check_bench.roofline_gate("r.json", _doc([]), tol=0.25)
-    assert ok
-
-
-def test_achieved_flops_gates_higher_is_better():
-    base = _doc([_roofline_row("fused", "interpret", "fp32", 10000.0,
-                               gflops=50.0)])
-    fresh = _doc([_roofline_row("fused", "interpret", "fp32", 25000.0,
-                                gflops=20.0)])
-    lines, ok = check_bench.compare_docs("r.json", base, fresh, tol=0.25)
-    assert not ok and any("REGRESSION" in ln for ln in lines)
-
-
-# ---------------------------------------------------------------------------
 # the intra-file controller gate (BENCH_controller_regret.json)
 # ---------------------------------------------------------------------------
 

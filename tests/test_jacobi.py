@@ -41,10 +41,12 @@ def test_matches_numpy(pivot, rotation):
     np.testing.assert_allclose(lhs, rhs, atol=5e-4)
 
 
+@pytest.mark.parametrize("pivot", ["parallel", "cyclic", "paper"])
 @pytest.mark.parametrize("angle", ["atan2", "rutishauser", "cordic"])
-def test_angle_modes(angle):
+def test_angle_modes(angle, pivot):
     c = jnp.asarray(_sym(16, 2))
-    res = jacobi_eigh(c, sweeps=10, angle=angle)
+    res = jacobi_eigh(c, sweeps=30 if pivot == "paper" else 10,
+                      angle=angle, pivot=pivot)
     ref = np.linalg.eigh(np.asarray(c))[0][::-1]
     np.testing.assert_allclose(np.asarray(res.eigenvalues), ref,
                                rtol=1e-3, atol=1e-3)
@@ -91,14 +93,67 @@ def test_round_robin_covers_all_pairs():
         assert len(seen) == n * (n - 1) // 2
 
 
-def test_jacobi_svd():
+@pytest.mark.parametrize("pivot", ["parallel", "cyclic", "paper"])
+def test_jacobi_svd(pivot):
     rng = np.random.default_rng(9)
     a = jnp.asarray(rng.standard_normal((40, 12)), jnp.float32)
-    u, s, vt = jacobi_svd(a, sweeps=12)
+    u, s, vt = jacobi_svd(a, sweeps=30 if pivot == "paper" else 12,
+                          pivot=pivot)
     ref = np.linalg.svd(np.asarray(a), compute_uv=False)
     np.testing.assert_allclose(np.asarray(s), ref, rtol=1e-4, atol=1e-4)
     recon = np.asarray(u) * np.asarray(s)[None, :] @ np.asarray(vt)
     np.testing.assert_allclose(recon, np.asarray(a), atol=1e-3)
+
+
+@pytest.mark.parametrize("pivot,rotation", [
+    ("cyclic", "rowcol"), ("cyclic", "matmul"), ("paper", "rowcol"),
+    ("paper", "matmul"), ("parallel", "matmul")])
+def test_indexed_paths_keep_bucket_padding_exact(pivot, rotation):
+    """The null-pivot guard on the pair-indexed rounds: a 7x7 problem
+    zero-padded into a 12 bucket keeps its padded eigenvalues exactly 0 and
+    its padded eigenvectors exactly the basis vectors e_j, with nothing
+    mixed into or out of the live block."""
+    n, nb = 7, 12
+    c = np.zeros((nb, nb), np.float32)
+    c[:n, :n] = _sym(n, 11)
+    res = jacobi_eigh(jnp.asarray(c), sweeps=6, pivot=pivot,
+                      rotation=rotation, sort=False)
+    w, V = np.asarray(res.eigenvalues), np.asarray(res.eigenvectors)
+    assert not np.any(w[n:])
+    np.testing.assert_array_equal(V[n:, n:], np.eye(nb - n))
+    assert not np.any(V[n:, :n]) and not np.any(V[:n, n:])
+    ref = np.linalg.eigh(c[:n, :n])[0]
+    np.testing.assert_allclose(np.sort(w[:n]), ref, rtol=1e-4, atol=1e-4)
+
+
+def _cordic_case(engine):
+    """(got, want) for one of the fixed-point CORDIC engines of
+    ``core.cordic`` against its float formula, over inputs that reach
+    every quadrant fold."""
+    from repro.core import cordic
+    rng = np.random.default_rng(13)
+    if engine == "atan2":
+        y = rng.uniform(-4, 4, 64).astype(np.float32)
+        x = rng.uniform(-4, 4, 64).astype(np.float32)
+        x[:4], y[:4] = (-1.0, -1.0, 2.0, -3.0), (1e-3, -1e-3, 0.0, 0.0)
+        return cordic.cordic_atan2(y, x), np.arctan2(y, x)
+    if engine == "sincos":
+        th = rng.uniform(-np.pi, np.pi, 64).astype(np.float32)
+        th[:3] = (np.pi, -np.pi / 2 - 0.1, np.pi / 2 + 0.1)
+        return jnp.stack(cordic.cordic_sincos(th)), np.stack(
+            [np.sin(th), np.cos(th)])
+    apq, app, aqq = (rng.uniform(-3, 3, 64).astype(np.float32)
+                     for _ in range(3))
+    return (jnp.stack(cordic.rotation_params_cordic(apq, app, aqq)),
+            np.stack(cordic.rotation_params(apq, app, aqq)))
+
+
+@pytest.mark.parametrize("engine", ["atan2", "sincos", "rotation_params"])
+def test_core_cordic_matches_float(engine):
+    """The Q2.29 CORDIC engines behind ``angle="cordic"`` agree with the
+    float formulas to the fixed-point granularity, in every quadrant."""
+    got, want = _cordic_case(engine)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=0, atol=1e-6)
 
 
 # ---------------------------------------------------------------------------
@@ -178,6 +233,35 @@ def _indexed_sweep_fn(angle):
                                                          angle=angle), None)
         return jax.lax.scan(step, (C, V), rounds)[0]
     return sweep
+
+
+def _pair_sets():
+    """A first and a last parallel round at n = 10, and one cyclic pair."""
+    from repro.core.jacobi import cyclic_pairs
+    rounds = round_robin_rounds(10)
+    return {"parallel_first": rounds[0], "parallel_last": rounds[-1],
+            "cyclic_pair": cyclic_pairs(10)[7]}
+
+
+@pytest.mark.parametrize("pairs", ["parallel_first", "parallel_last",
+                                   "cyclic_pair"])
+@pytest.mark.parametrize("angle", ANGLES)
+def test_indexed_round_equals_reference_step(angle, pairs):
+    """One round of ``_sweep_scan`` (rowcol) is ``kref.jacobi_sweep_step``
+    bit for bit: the reference the tournament tests hold the served round
+    to is the round ``jacobi_eigh`` runs on its indexed paths."""
+    from repro.core.cordic import ANGLE_MODES
+    from repro.core.jacobi import _sweep_scan
+    from repro.kernels import ref as kref
+    C, V = _padded_problem(10, seed=5)
+    P = jnp.asarray(_pair_sets()[pairs], jnp.int32)
+    got = jax.jit(lambda C, V: _sweep_scan(C, V, P[None], ANGLE_MODES[angle],
+                                           "rowcol", None))(C, V)
+    want = jax.jit(lambda C, V: kref.jacobi_sweep_step(C, V, P,
+                                                       angle=angle))(C, V)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    assert np.any(np.asarray(got[1]) != np.eye(10, dtype=np.float32))
 
 
 def _padded_problem(n, batch=None, seed=0):

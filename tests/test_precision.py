@@ -1,7 +1,6 @@
-"""Mixed-precision policy: bf16-streamed / fp32 paths vs the fp64
-subprocess oracle, held to the documented ``ERROR_BUDGETS``; and the
-bitwise fp32 contract between fused and unfused paths (the budget for
-fp32-vs-fp32 is zero, so it is asserted as array_equal, not a norm).
+"""Mixed-precision policy: bf16-streamed / fp32 paths, the reference Gram
+and the served solvers, vs the fp64 subprocess oracle, held to the
+documented ``ERROR_BUDGETS``.
 
 The oracle runs ``JAX_ENABLE_X64=1`` in a child process (the x64 switch
 is global and import-time, so this process never flips it); one oracle
@@ -14,9 +13,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import PCAConfig, precision as prec
-from repro.core.covariance import blocked_covariance
-from repro.core.jacobi import jacobi_eigh, jacobi_svd
-from repro.kernels import ops as kops
+from repro.core.jacobi import jacobi_eigh
+from repro.kernels import ref as kref
+from repro.serving.solver import build_solver_fn, jacobi_svd_batched
 
 M, N, SWEEPS = 256, 12, 20
 
@@ -66,10 +65,18 @@ def test_serving_process_is_not_x64():
 # budgets vs the fp64 oracle
 # ---------------------------------------------------------------------------
 
+def _gram(X, precision):
+    """The policy's Gram: operands cast to its streaming dtype, summed in
+    its accumulator dtype (bf16 operands, fp32 accumulation for
+    ``bf16_fp32acc``)."""
+    return kref.covariance_gram(
+        jnp.asarray(X).astype(prec.operand_dtype(precision)),
+        acc_dtype=prec.acc_dtype(precision))
+
+
 @pytest.mark.parametrize("precision", ["fp32", "bf16_fp32acc"])
 def test_covariance_budget(X, oracle_cov, precision):
-    C = kops.covariance(X, block_m=64, precision=precision,
-                        backend="interpret")
+    C = _gram(X, precision)
     err = prec.rel_frobenius(np.asarray(C), oracle_cov["C"])
     budget = prec.ERROR_BUDGETS[precision]["covariance"]
     assert err < budget, f"{precision} covariance err {err} >= {budget}"
@@ -77,8 +84,7 @@ def test_covariance_budget(X, oracle_cov, precision):
 
 @pytest.mark.parametrize("precision", ["fp32", "bf16_fp32acc"])
 def test_eigh_budget(X, oracle_eigh, precision):
-    C = kops.covariance(X, block_m=64, precision=precision,
-                        backend="interpret")
+    C = _gram(X, precision)
     res = jacobi_eigh(np.asarray(C), sweeps=SWEEPS)
     err = prec.rel_frobenius(np.asarray(res.eigenvalues),
                              oracle_eigh["eigenvalues"])
@@ -88,34 +94,24 @@ def test_eigh_budget(X, oracle_eigh, precision):
 
 @pytest.mark.parametrize("precision", ["fp32", "bf16_fp32acc"])
 def test_svd_budget(X, oracle_svd, precision):
-    _, s, _ = jacobi_svd(X, sweeps=SWEEPS, fused=True,
-                         fused_backend="interpret", precision=precision)
-    err = prec.rel_frobenius(np.asarray(s), oracle_svd["S"])
+    res = jacobi_svd_batched(X[None], sweeps=SWEEPS, precision=precision)
+    err = prec.rel_frobenius(np.asarray(res.S[0]), oracle_svd["S"])
     budget = prec.ERROR_BUDGETS[precision]["svd"]
     assert err < budget, f"{precision} svd err {err} >= {budget}"
 
 
-# ---------------------------------------------------------------------------
-# fp32 fused-vs-unfused is bitwise (budget zero, asserted exactly)
-# ---------------------------------------------------------------------------
-
-def test_fp32_fused_covariance_bitwise(X):
-    fused = blocked_covariance(X, block_m=64, fused=True,
-                               backend="interpret", precision="fp32")
-    unfused = jax.jit(lambda a: blocked_covariance(a, block_m=64))(X)
-    np.testing.assert_array_equal(np.asarray(fused), np.asarray(unfused))
-
-
-def test_fp32_fused_eigh_bitwise():
-    rng = np.random.default_rng(7)
-    a = rng.standard_normal((10, 10)).astype(np.float32)
-    C = (a + a.T) / 2
-    u = jacobi_eigh(C, sweeps=8, fused=False)
-    f = jacobi_eigh(C, sweeps=8, fused=True, fused_backend="interpret")
-    np.testing.assert_array_equal(np.asarray(u.eigenvalues),
-                                  np.asarray(f.eigenvalues))
-    np.testing.assert_array_equal(np.asarray(u.eigenvectors),
-                                  np.asarray(f.eigenvectors))
+@pytest.mark.parametrize("precision", ["fp32", "bf16_fp32acc"])
+def test_served_pca_budget(X, oracle_eigh, precision):
+    """The served ``pca`` executable, unstandardized so its covariance is
+    the oracle's X^T X, keeps the policy's eigenvalue budget."""
+    fn = build_solver_fn("pca", PCAConfig(sweeps=SWEEPS, standardize=False,
+                                          precision=precision))
+    res = jax.jit(fn)(X[None], jnp.asarray([M], jnp.int32),
+                      jnp.asarray([N], jnp.int32))
+    err = prec.rel_frobenius(np.asarray(res.eigenvalues[0]),
+                             oracle_eigh["eigenvalues"])
+    budget = prec.ERROR_BUDGETS[precision]["eigh"]
+    assert err < budget, f"{precision} served pca err {err} >= {budget}"
 
 
 def test_bf16_halves_streamed_bytes():
@@ -131,7 +127,6 @@ def test_fp32_policy_matmuls_run_at_highest_precision(op, policy, highest):
     """A TPU runs an fp32 dot at DEFAULT precision as one bf16 pass, which
     misses the fp32 budgets; the fp32 policy's plain matmuls therefore
     carry HIGHEST into the lowered program, and the bf16 lane does not."""
-    from repro.serving.solver import build_solver_fn
     fn = build_solver_fn(op, PCAConfig(T=8, S=2, sweeps=2,
                                        precision=policy))
     structs = (jax.ShapeDtypeStruct((2, 32, 8), jnp.float32),

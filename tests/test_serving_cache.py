@@ -2,6 +2,7 @@
 in-memory LRU, the persistent AOT tier's failure modes (corruption,
 environment drift, concurrent warmers), warmup, and the rank-deficiency
 fix in the SVD back-projection that rides along."""
+import dataclasses
 import os
 import pathlib
 import pickle
@@ -80,13 +81,16 @@ def test_solver_key_ignores_scheduling_facts():
 
 
 def test_solver_key_carries_precision_and_fused():
-    """Mixed-precision policy and fused routing change the compiled
-    executable, so they must fragment the key -- unlike T/S."""
+    """The mixed-precision policy changes the compiled executable, so it
+    must fragment the key -- unlike T/S.  The fused kernel path is gone:
+    neither the key nor the config carries a ``fused`` field."""
     base = SolverKey.from_config(PCAConfig(T=8, S=2))
-    assert base.precision == "fp32" and base.fused is False
+    assert base.precision == "fp32"
     assert base != SolverKey.from_config(
         PCAConfig(T=8, S=2, precision="bf16_fp32acc"))
-    assert base != SolverKey.from_config(PCAConfig(T=8, S=2, fused=True))
+    assert "fused" not in {f.name for f in dataclasses.fields(SolverKey)}
+    with pytest.raises(TypeError):
+        PCAConfig(fused=True)
     # content hash fragments with them too (the disk-tier file name)
     h = lambda k: cache_mod.content_hash("pca", (8, 8), 2, k, None)
     assert h(base) != h(SolverKey.from_config(

@@ -5,6 +5,7 @@ claim -- a spec-built server serves bitwise-identically to the
 kwarg-built server it replaces."""
 import dataclasses
 import json
+import pathlib
 import types
 import warnings
 
@@ -16,6 +17,8 @@ from repro.serving import (BucketPolicy, CacheSpec, ControllerSpec,
                            ExecutionSpec, ObsSpec, PCAServer,
                            SchedulingSpec, ServerSpec, SpecConflictError,
                            build_server, resolve_spec, validate_args)
+
+REPO_ROOT = pathlib.Path(__file__).resolve().parent.parent
 
 
 def _sym(n, seed=0):
@@ -93,6 +96,27 @@ def test_spec_json_round_trip(tmp_path):
     partial = ServerSpec.from_json('{"scheduling": {"T": 8}}')
     assert partial.scheduling.T == 8
     assert partial.execution == ExecutionSpec()
+
+
+@pytest.mark.parametrize("fused", [False, True])
+def test_spec_from_json_retired_fused_key(fused):
+    """``execution.fused`` named the removed fused kernel path.  ``false``
+    (which the benchmark configs still carry) loads as if the key were
+    absent; ``true`` is refused rather than served by the unfused path."""
+    doc = json.loads(ServerSpec().to_json())
+    doc["execution"]["fused"] = fused
+    if fused:
+        with pytest.raises(ValueError, match="fused"):
+            ServerSpec.from_json(json.dumps(doc))
+        return
+    assert ServerSpec.from_json(json.dumps(doc)) == ServerSpec()
+    configs = sorted(REPO_ROOT.glob("bench/configs/*.json"))
+    assert configs
+    for path in configs:
+        doc = json.loads(path.read_text())["server_spec"]
+        loaded = ServerSpec.from_json(json.dumps(doc))
+        doc.get("execution", {}).pop("fused", None)
+        assert loaded == ServerSpec.from_json(json.dumps(doc))
 
 
 def test_spec_from_args_and_cli_round_trip():

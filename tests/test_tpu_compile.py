@@ -8,9 +8,6 @@ inside a module-scoped fixture, never at import, so every test worker
 collects the same tests and only the worker given this file loads the TPU
 compiler.
 
-Refused today, and so not compiled here: ``jacobi_sweep`` (Mosaic's gather
-lowering asserts on the pivot gather) and the fused ``covariance`` kernel
-at n >= 784 (its (n, n) accumulator and full-width panels exceed VMEM).
 The MM-Engine needs a matmul block T that is a multiple of 128: the
 compiler refuses T=16 blocks, so ``backend="pallas"`` serves with T=128.
 """
@@ -102,8 +99,6 @@ KERNELS = {
         ((784, 70000), (70000, 784))),
     "dle_find_pivot": (
         lambda c: kops.dle_find_pivot(c, backend="pallas"), ((784, 784),)),
-    "covariance": (
-        lambda x: kops.covariance(x, backend="pallas"), ((4096, 256),)),
     "cordic_rotate_256": (
         lambda a, b, c: kops.cordic_rotate(a, b, c, backend="pallas"),
         ((256,), (256,), (256,))),
