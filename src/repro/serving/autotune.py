@@ -46,7 +46,8 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from repro.core.pca import PCAConfig
-from .batching import BucketPolicy, POLICIES
+from repro.parallel.sharding import pad_to_multiple
+from .batching import BucketPolicy, POLICIES, batch_rungs
 from .sharded import LocalExecutor, mesh_executor
 from .stats import ServingStats
 
@@ -428,6 +429,8 @@ class CostModel:
       device   bucket area (to the solve-work power) x padded batch x
                flush count / device rate -- the padding-waste and
                batching term: bigger buckets and emptier flushes cost.
+               A bucket's last, partial flush is charged at its rung
+               (``batching.batch_rungs``), as the engine pads it.
       host     per-flush dispatch cost (stack/pad/launch/unpack), minus
                the fraction the plan's pipeline depth hides behind device
                execution.  Occupancy is ``1 - 1/max_inflight`` scaled by
@@ -435,10 +438,11 @@ class CostModel:
                (``overlap_frac``) when it was captured under a pipelined
                plan -- a host that never reached its theoretical overlap
                will not magically reach it under the candidate either.
-      compile  one charge per distinct executable the plan needs
-               (op x bucket x padded-batch), amortized against the
-               executable cache: steady-state traffic compiles once, so
-               plans that shatter traffic across many buckets pay here.
+      compile  one charge per (op, bucket) the plan needs, amortized
+               against the executable cache: steady-state traffic
+               compiles once, so plans that shatter traffic across many
+               buckets pay here.  A bucket's rungs compile together at
+               its first flush and share the one charge.
     """
     device_work_per_s: float = 2.0e9
     host_s_per_flush: float = 1.0e-3
@@ -508,17 +512,23 @@ class CostModel:
         occupancy = self.occupancy(plan, profile)
         device_s = host_s = hidden_s = 0.0
         n_exec = 0
-        padded_batch = int(math.ceil(plan.max_batch / shards)) * shards
+        rungs = batch_rungs(plan.max_batch,
+                            lambda b: pad_to_multiple(b, shards))
+        host_flush = (self.host_s_per_flush
+                      + self.host_s_per_request * plan.max_batch)
         for (op, bucket), n in sorted(per_bucket.items()):
-            flushes = math.ceil(n / plan.max_batch)
-            dev_flush = (padded_batch / shards) * solve_work(op, bucket) \
-                / self.device_work_per_s
-            host_flush = (self.host_s_per_flush
-                          + self.host_s_per_request * plan.max_batch)
+            full, rest = divmod(n, plan.max_batch)
             n_exec += 1
-            device_s += flushes * dev_flush
-            host_s += flushes * host_flush
-            hidden_s += flushes * occupancy * min(host_flush, dev_flush)
+            # full flushes run at the top rung, the partial one at the
+            # smallest rung that holds it, as the engine dispatches them
+            for flushes, live in ((full, plan.max_batch),
+                                  (int(rest > 0), rest)):
+                rung = next(r for r in rungs if r >= live)
+                dev_flush = (rung / shards) * solve_work(op, bucket) \
+                    / self.device_work_per_s
+                device_s += flushes * dev_flush
+                host_s += flushes * host_flush
+                hidden_s += flushes * occupancy * min(host_flush, dev_flush)
         compile_s = n_exec * self.compile_s_per_executable
         # deadline term: when the profile measured an arrival rate, a plan
         # slower than the offered load queues unboundedly -- every second

@@ -30,7 +30,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Optional, Sequence, Tuple
+from typing import Callable, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -115,39 +115,47 @@ def _zero_outside(slot: np.ndarray, old: Tuple[int, ...],
 
 
 class StagingSlab:
-    """One host batch slab, (bp, *bucket), zero outside its live data.
+    """One host batch slab, (slots, *bucket), zero outside its live data.
 
     ``extents[i]`` is the shape the last occupant of slot ``i`` wrote
     (``None``: the slot is all zero), so a refill zeroes only what the new
-    occupant does not overwrite.
+    occupant does not overwrite.  ``batch`` is the leading part of
+    ``array`` the last ``fill`` staged: the flush's padded batch, what
+    the device is given.
     """
 
-    __slots__ = ("key", "array", "extents")
+    __slots__ = ("key", "array", "extents", "batch")
 
-    def __init__(self, key: Tuple, bucket: Tuple[int, ...], bp: int, dtype):
+    def __init__(self, key: Tuple, bucket: Tuple[int, ...], slots: int,
+                 dtype):
         self.key = key
-        self.array = np.zeros((bp, *bucket), dtype)
-        self.extents: list = [None] * bp
+        self.array = np.zeros((slots, *bucket), dtype)
+        self.extents: list = [None] * slots
+        self.batch = self.array
 
-    def fill(self, mats: Sequence[np.ndarray]) -> None:
-        """Write ``mats`` into the leading slots; the rest become filler."""
+    def fill(self, mats: Sequence[np.ndarray], bp: int) -> None:
+        """Write ``mats`` into the leading slots and make the rest of the
+        first ``bp`` filler.  Slots past ``bp`` keep what they held: no
+        flush of this batch reads them, and a later fill that reaches
+        them zeroes what it must."""
         bucket = self.array.shape[1:]
-        if len(mats) > len(self.extents):
-            raise ValueError(f"{len(mats)} matrices for a slab of "
-                             f"{len(self.extents)} slots")
+        if len(mats) > bp or bp > len(self.extents):
+            raise ValueError(f"{len(mats)} matrices in a batch of {bp} "
+                             f"for a slab of {len(self.extents)} slots")
         for m in mats:
             if m.ndim != len(bucket) or any(
                     d > b for d, b in zip(m.shape, bucket)):
                 raise ValueError(
                     f"matrix shape {m.shape} does not fit bucket {bucket}")
-        for i, extent in enumerate(self.extents):
-            slot = self.array[i]
+        for i in range(bp):
+            slot, extent = self.array[i], self.extents[i]
             new = mats[i].shape if i < len(mats) else None
             if new is not None:
                 slot[tuple(slice(0, d) for d in new)] = mats[i]
             if extent is not None:
                 _zero_outside(slot, extent, new or (0,) * slot.ndim)
             self.extents[i] = new
+        self.batch = self.array[:bp]
 
 
 class StagingPool:
@@ -156,10 +164,13 @@ class StagingPool:
     A padded slab built afresh for every flush is allocated, faulted in
     and written whole; for a 70000x784 request at a batch of 4 that is
     about 1.1 GB, nearly all of it zeros the previous flush had already
-    written.  The pool keeps slabs of (bucket, padded batch, dtype) and
-    writes each flush's requests into a free one of its key in place; its
-    bytes equal ``stack_requests`` plus zero filler, the reference the
-    tests hold it to.
+    written.  The pool keeps slabs of (bucket, slots, dtype) and writes
+    each flush's requests into a free one of its key in place; the
+    flush's padded batch ``bp`` is the slab's first ``bp`` slots
+    (``StagingSlab.batch``), so the server gives every slab of a bucket
+    the slots of its largest rung and flushes of every rung share them.
+    A slab's batch equals ``stack_requests`` plus zero filler byte for
+    byte, the reference the tests hold it to.
 
     A slab is taken at dispatch and given back (``release``) once its
     flush has retired, never while a flush that read it is in flight: the
@@ -178,18 +189,20 @@ class StagingPool:
         self._free: list = []      # least recently released first
 
     def take(self, mats: Sequence[np.ndarray], bucket: Sequence[int],
-             bp: int) -> Tuple[StagingSlab, bool]:
-        """A slab holding ``mats`` in its first slots and zero filler up to
-        ``bp``, and whether it was reused (False: freshly allocated)."""
+             bp: int, slots: Optional[int] = None
+             ) -> Tuple[StagingSlab, bool]:
+        """A slab of ``slots`` slots (default ``bp``) whose batch holds
+        ``mats`` in its first slots and zero filler up to ``bp``, and
+        whether it was reused (False: freshly allocated)."""
         bucket = tuple(int(d) for d in bucket)
-        key = (bucket, bp, np.result_type(*mats))
+        key = (bucket, max(slots or bp, bp), np.result_type(*mats))
         for i in range(len(self._free) - 1, -1, -1):
             if self._free[i].key == key:
                 slab, reused = self._free.pop(i), True
                 break
         else:
-            slab, reused = StagingSlab(key, bucket, bp, key[2]), False
-        slab.fill(mats)
+            slab, reused = StagingSlab(key, bucket, key[1], key[2]), False
+        slab.fill(mats, bp)
         return slab, reused
 
     def release(self, slab: StagingSlab, max_free: int) -> None:
@@ -197,6 +210,19 @@ class StagingPool:
         most recently released slabs."""
         self._free.append(slab)
         del self._free[:-max_free]
+
+
+def batch_rungs(max_batch: int,
+                round_batch: Callable[[int], int]) -> Tuple[int, ...]:
+    """The padded batches a bucket's flushes dispatch at with
+    ``pad_batches``, ascending: the powers of two below ``max_batch`` and
+    ``max_batch`` itself, each passed through the executor's
+    ``round_batch`` (a mesh's data-axis multiple), without repeats.  A
+    flush of b live requests runs at the smallest rung that holds b, so
+    a lone request is not solved beside ``max_batch - 1`` zero matrices,
+    and a bucket needs one executable per rung."""
+    sizes = {1 << i for i in range((max_batch - 1).bit_length())}
+    return tuple(sorted({round_batch(b) for b in sizes | {max_batch}}))
 
 
 def padding_waste(shape: Sequence[int], bucket: Sequence[int]) -> float:

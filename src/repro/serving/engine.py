@@ -6,9 +6,12 @@ buckets, and the S-array axis (``solver``) retires up to S same-bucket
 requests per dispatch.  Requests queue per (op, bucket); a queue flushes when
 it reaches S (full microbatch) or when its oldest request's deadline expires
 (``poll``).  Each (op, bucket, batch) triple maps to one jitted executable
-held in an explicit cache -- with ``pad_batches=True`` partial flushes are
-zero-padded up to S so steady-state traffic runs entirely on cached
-executables and never recompiles.
+held in an explicit cache -- with ``pad_batches=True`` a flush is
+zero-padded up to its *rung*, the smallest of the bucket's padded batches
+(the powers of two below S, and S; ``batching.batch_rungs``) that holds
+it, and a bucket's first flush builds the executable of every rung, so
+steady-state traffic runs entirely on cached executables and never
+recompiles, and a lone request is not solved beside S - 1 zero matrices.
 
 A flush is a three-stage pipeline, the software image of the paper's
 block-streaming (keep the S arrays busy while the next block streams in):
@@ -57,7 +60,7 @@ import numpy as np
 
 from repro.core.pca import PCAConfig
 from repro.obs.tracing import stage
-from .batching import BucketPolicy, StagingPool, padding_waste
+from .batching import BucketPolicy, StagingPool, batch_rungs, padding_waste
 from .cache import DEFAULT_MAX_ENTRIES, ExecutableCache, SolverKey
 from .inflight import InFlightFlush, InFlightQueue
 from .sharded import LocalExecutor
@@ -264,8 +267,11 @@ class PCAServer:
       policy: bucket policy (default: tile-mode with T = config.T).
       max_batch: requests per device batch (default: config.S).
       max_delay_s: default flush deadline for a queued request.
-      pad_batches: zero-pad partial flushes up to max_batch so every bucket
-        uses a single cached executable (no recompiles on timeout flushes).
+      pad_batches: zero-pad each flush up to its rung, the smallest of the
+        powers of two below max_batch and max_batch itself (rounded by the
+        executor) that holds it; a bucket's first flush compiles every
+        rung ahead of time, so no later flush of it compiles, whatever
+        its live count (timeout flushes included).
       backend_router: optional (op, bucket) -> backend-name routing so
         different buckets run on different kernel backends in one server
         (e.g. ``threshold_router(128)``: big buckets on Pallas, small ones
@@ -437,6 +443,9 @@ class PCAServer:
             "serve_stage_slabs_total",
             "Host staging slabs taken by dispatch, reused or allocated.",
             ("event",))
+        self._m_rungs = m.counter(
+            "serve_flush_rungs_total",
+            "Flushes dispatched, by padded batch.", ("rung",))
         self._m_warm = m.counter(
             "serve_warmup_executables_total",
             "Executables pre-built by warmup(), by cache source.",
@@ -667,38 +676,48 @@ class PCAServer:
         t_dispatch = clock()
         b = len(queue)
         with stage("stack", clock, t_dispatch) as stack:
-            bp = max(self.max_batch if self.pad_batches else b, b)
-            # the executor may demand a larger batch (a mesh pads up to the
-            # next data-axis multiple so every shard gets an identical slab)
-            bp = self.executor.round_batch(bp)
+            # the smallest rung that holds the flush; the executor may
+            # demand a larger batch (a mesh pads up to the next data-axis
+            # multiple so every shard gets an identical slab)
+            rungs = self._rungs(self.executor, self.max_batch)
+            bp = next((r for r in rungs if r >= b),
+                      self.executor.round_batch(b))
             # a kept slab, zero outside its live data: slots past b are
-            # inert filler (zero matrices, zero live coordinates)
+            # inert filler (zero matrices, zero live coordinates).  It has
+            # the slots of the largest rung, so flushes of every rung of
+            # the bucket share it, each staged into its first bp slots
             slab, reused = self._staging.take(
-                [e.matrix for e in queue], bucket, bp)
+                [e.matrix for e in queue], bucket, bp,
+                slots=max(rungs, default=bp))
             n_active = np.zeros((len(bucket), bp), np.int32)
             n_active[:, :b] = np.asarray(
                 [e.matrix.shape for e in queue], np.int32).T
         backend = self.backend_for(op, bucket)
         with stage("lookup", clock, stack.end) as lookup:
             fn, source = self._executable(op, bucket, bp, backend, sweeps)
+            if source != "memory":
+                # the bucket's first flush builds its other rungs too, so
+                # no later flush of it compiles, whatever its live count
+                for rung in rungs:
+                    self._executable(op, bucket, rung, backend, sweeps)
         obs = self.obs
         if obs is not None:
             # reserve the flush span's id now; the span itself is recorded
             # at retire time, when its end is known
             flush_span = obs.tracer.new_id()
             if source != "memory":
-                # the executable *build*: a jit-wrapper construction on the
-                # memory-only path (XLA itself compiles lazily inside the
-                # first launch, landing in the launch span), a full AOT
-                # compile when the disk tier is armed, or a deserialize on
-                # a disk hit ("aot_load")
+                # the executable *build*: a full AOT compile of every rung
+                # with ``pad_batches`` or a disk tier armed, else a
+                # jit-wrapper construction (XLA itself compiles lazily
+                # inside the first launch, landing in the launch span), or
+                # a deserialize on a disk hit ("aot_load")
                 obs.tracer.complete(
                     "compile" if source == "compile" else "aot_load",
                     ts=lookup.start, end=lookup.end, cat="compile",
                     track="flushes", parent=flush_span, op=op,
                     bucket=list(bucket), batch=bp, backend=str(backend))
         hit = source != "compile"
-        flush = self.executor.submit(fn, slab.array, n_active, clock=clock,
+        flush = self.executor.submit(fn, slab.batch, n_active, clock=clock,
                                      start=lookup.end)
         flush.seq = next(self._seq)
         flush.key = key
@@ -726,6 +745,7 @@ class PCAServer:
             self._m_batch.labels(op, bucket).observe(b, now=t_dispatch)
             self._m_slabs.labels(
                 "reused" if reused else "allocated").inc(now=t_dispatch)
+            self._m_rungs.labels(bp).inc(now=t_dispatch)
             self._m_depth.set(self._inflight.depth, now=t_dispatch)
             self._m_queued.set(self.pending(), now=t_dispatch)
         # back-pressure: block on the oldest flush until the cap holds.
@@ -892,7 +912,9 @@ class PCAServer:
         subset the compiled solver actually depends on -- so configs that
         differ only in scheduling facts (T, S) share one executable.  With
         a disk tier armed, misses compile ahead-of-time (the result is
-        serializable); without one, the executor's shared jit wrapper.
+        serializable); without one they compile ahead-of-time too under
+        ``pad_batches``, whose flushes must never compile, and are the
+        executor's shared jit wrapper otherwise.
         The explicit (config, executor) arguments let ``apply_plan``
         pre-warm an *incoming* plan's executables before the swap.
         """
@@ -904,7 +926,7 @@ class PCAServer:
         fn, source = self._cache.lookup(key)
         if fn is None:
             source = "compile"
-            if self._cache.disk is not None:
+            if self._cache.disk is not None or self.pad_batches:
                 fn = executor.aot_compile(op, cfg, bucket, batch)
                 self._cache.store(key, fn, persist=True)
             else:
@@ -927,20 +949,28 @@ class PCAServer:
         return [(row[0], tuple(row[1]),
                  int(row[2]) if len(row) > 2 else 1) for row in rows]
 
+    def _rungs(self, executor, max_batch) -> Tuple[int, ...]:
+        """The padded batches a bucket's flushes dispatch at with
+        ``pad_batches``; none without it (a flush then runs at its own
+        size, rounded by the executor)."""
+        if self.pad_batches:
+            return batch_rungs(max_batch, executor.round_batch)
+        return ()
+
     def _enumerate_keys(self, shapes, policy, executor, config,
                         max_batch) -> List[Tuple]:
         """Distinct (op, bucket, batch, backend) executables the given
-        (op, shape[, count]) rows imply under the given plan facts.  The
-        batch is the plan's padded flush size -- the one executable
-        steady-state ``pad_batches`` traffic dispatches.
+        (op, shape[, count]) rows imply under the given plan facts: one
+        per rung of each bucket (``_rungs``), the executables steady-state
+        ``pad_batches`` traffic dispatches.
 
-        Keys come back in descending traffic weight (sum of the counts of
-        the shapes that bucket onto them), ties broken by first
-        appearance: warmup compiles the executables the profile says will
-        be hit most *first*, so an interrupted or still-running warmup has
-        already armed the highest-traffic (i.e. SLO-critical) paths."""
+        Buckets come back in descending traffic weight (sum of the counts
+        of the shapes that bucket onto them), ties broken by first
+        appearance, each bucket's rungs ascending: warmup compiles the
+        executables the profile says will be hit most *first*, so an
+        interrupted or still-running warmup has already armed the
+        highest-traffic (i.e. SLO-critical) paths."""
         weight, order = {}, {}
-        batch = executor.round_batch(max_batch)
         for row in shapes:
             op, shape = row[0], row[1]
             n = int(row[2]) if len(row) > 2 else 1
@@ -948,12 +978,17 @@ class PCAServer:
             backend = (self.backend_router(op, bucket)
                        if self.backend_router is not None
                        else config.backend)
-            k = (op, bucket, batch, backend)
+            k = (op, bucket, backend)
             if k not in weight:
                 weight[k] = 0
                 order[k] = len(order)
             weight[k] += n
-        return sorted(weight, key=lambda k: (-weight[k], order[k]))
+        rungs = (self._rungs(executor, max_batch)
+                 or (executor.round_batch(max_batch),))
+        return [(op, bucket, batch, backend)
+                for op, bucket, backend in sorted(
+                    weight, key=lambda k: (-weight[k], order[k]))
+                for batch in rungs]
 
     def warmup_keys(self, profile) -> List[Tuple]:
         """The distinct (op, bucket, batch, backend) executables

@@ -118,10 +118,11 @@ class LocalExecutor:
         """Ahead-of-time compile one concrete (bucket, batch) executable.
 
         The ``jax.stages.Compiled`` this returns is what the persistent
-        cache tier serializes (``serving.cache.DiskCache``) and what
-        ``PCAServer.warmup`` pre-builds: calling it runs zero tracing and
-        zero XLA work.  It shares the memoized polymorphic wrapper, so a
-        later same-shape JIT call reuses the identical compilation.
+        cache tier serializes (``serving.cache.DiskCache``) and what a
+        ``pad_batches`` server builds for every rung of a bucket: calling
+        it runs zero tracing and zero XLA work.  It shares the memoized
+        polymorphic wrapper, so a later same-shape JIT call reuses the
+        identical compilation.
         """
         return self.compile(op, config, bucket, batch).lower(
             *solver_structs(bucket, batch)).compile()
@@ -145,12 +146,16 @@ class LocalExecutor:
         the handle carries their ends as ``t_put`` and ``t_launched``.
         """
         with stage("put", clock, start) as put:
-            args = (jnp.asarray(batch), *map(jnp.asarray, n_active))
+            args = self.place(batch, n_active)
         with stage("launch", clock, put.end) as launch:
             out = fn(*args)
         flush = InFlightFlush(out, n_shards=self.n_shards)
         flush.t_put, flush.t_launched = put.end, launch.end
         return flush
+
+    def place(self, batch, n_active) -> Tuple[jax.Array, ...]:
+        """Copy one flush's slab and its true-size vectors to the device."""
+        return (jnp.asarray(batch), *map(jnp.asarray, n_active))
 
     def run(self, fn: Callable, batch, n_active):
         """Blocking compatibility path: ``submit(...).result()``."""
@@ -224,6 +229,15 @@ class MeshExecutor(LocalExecutor):
         out_sh = self.rules.sharding_tree(batch_axes(out_struct), self.mesh)
         return jax.jit(fn, in_shardings=in_sh, out_shardings=out_sh,
                        **_donate_kwargs())
+
+    def place(self, batch, n_active) -> Tuple[jax.Array, ...]:
+        """Copy the flush straight onto the data axis, in the shardings
+        the executable was compiled with: an ahead-of-time executable
+        given single-device arrays would compile a reshard on its first
+        call."""
+        arrays = (batch, *n_active)
+        return jax.device_put(arrays, self.rules.sharding_tree(
+            batch_axes(arrays), self.mesh))
 
     def describe(self) -> str:
         shape = "x".join(f"{k}={v}" for k, v in self.mesh.shape.items())

@@ -239,8 +239,9 @@ def test_server_routes_buckets_to_different_backends():
         np.testing.assert_allclose(r.eigenvalues, ref, rtol=1e-3, atol=1e-3)
     routed = {(r.bucket, r.backend) for r in srv.stats.records}
     assert routed == {((8, 8), None), ((24, 24), "interpret")}
-    # distinct backend-qualified cache entries, one per bucket
-    assert len(srv._cache) == 2
+    # distinct backend-qualified cache entries, one per bucket and rung
+    assert sorted((k[1], k[2]) for k in srv._cache) == [
+        ((8, 8), 1), ((8, 8), 2), ((24, 24), 1), ((24, 24), 2)]
     assert {k[3].backend for k in srv._cache} == {None, "interpret"}
 
 
@@ -267,4 +268,5 @@ def test_same_bucket_two_backends_two_cache_entries():
     assert srv.stats.cache_misses == 1
     srv.backend_router = threshold_router(16, large=None, small=None)
     srv.solve_many([_sym(20, seed=3), _sym(20, seed=4)], op="eigh")
-    assert srv.stats.cache_misses == 2 and len(srv._cache) == 2
+    # two backends x rungs 1 and 2
+    assert srv.stats.cache_misses == 2 and len(srv._cache) == 4

@@ -1,8 +1,8 @@
 """What a flush fetches and what its tickets keep: ``FlushRecord``'s
-``fetched_bytes`` counts the whole result tree gathered home, batch filler
-included, and ``answer_bytes`` only the true-shape slices the tickets
-keep; with obs attached an svd flush counts the columns its cutoff
-zeroed in ``serve_svd_dead_columns_total``."""
+``fetched_bytes`` counts the whole result tree gathered home, every slot
+of the flush's rung included, and ``answer_bytes`` only the true-shape
+slices the tickets keep; with obs attached an svd flush counts the
+columns its cutoff zeroed in ``serve_svd_dead_columns_total``."""
 import numpy as np
 import pytest
 
@@ -33,22 +33,25 @@ def _kept(op, m, n):
     return F32 * (n * n + 5 * n)   # components and five vectors
 
 
+SHAPES = [(20, 6), (21, 5), (24, 8), (17, 3)]
+
+
 @pytest.mark.parametrize("op", ["svd", "pca"])
-@pytest.mark.parametrize("shapes", [[(20, 6)], [(20, 6), (21, 5)]],
-                         ids=["one", "two"])
-def test_flush_counts_fetched_and_kept_bytes(op, shapes):
-    """One or two requests in a (24, 8) bucket padded to a batch of 4:
-    every slot of the result tree is fetched, only the true shapes are
-    kept."""
+@pytest.mark.parametrize("b, rung", [(1, 1), (2, 2), (3, 4), (4, 4)],
+                         ids=["one", "two", "three", "four"])
+def test_flush_counts_fetched_and_kept_bytes(op, b, rung):
+    """One to four requests in a (24, 8) bucket of max_batch 4, padded to
+    the smallest rung (1, 2 or 4) that holds them: every slot of the
+    rung's result tree is fetched, only the true shapes are kept."""
     rng = np.random.default_rng(1)
     srv = _server()
+    shapes = SHAPES[:b]
     for shape in shapes:
         srv.submit(rng.standard_normal(shape).astype(np.float32), op=op)
     srv.drain()
     (fr,) = srv.stats.flush_records
-    assert (fr.batch_size, fr.padded_batch, fr.bucket) == (
-        len(shapes), 4, (24, 8))
-    assert fr.fetched_bytes == _fetched(op, 4, 24, 8)
+    assert (fr.batch_size, fr.padded_batch, fr.bucket) == (b, rung, (24, 8))
+    assert fr.fetched_bytes == _fetched(op, rung, 24, 8)
     assert fr.answer_bytes == sum(_kept(op, *s) for s in shapes)
     assert fr.answer_bytes < fr.fetched_bytes
 

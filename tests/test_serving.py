@@ -253,11 +253,13 @@ def test_engine_executable_cache_hits_on_repeated_shapes():
     assert srv.stats.cache_misses == 1 and srv.stats.cache_hits == 0
     [srv.submit(_sym(6, seed=10 + i)) for i in range(4)]
     assert srv.stats.cache_hits == 1             # same (op, bucket, batch)
-    # timeout-style partial flush pads the batch -> same executable, still hit
+    # a timeout-style partial flush runs at rung 1, whose executable the
+    # bucket's first flush built beside rungs 2 and 4 -> still a hit
     srv.submit(_sym(7, seed=20))
     srv.drain()
     assert srv.stats.cache_hits == 2
-    assert len(srv._cache) == 1
+    assert srv.stats.flush_records[-1].padded_batch == 1
+    assert sorted(k[2] for k in srv._cache) == [1, 2, 4]
 
 
 def test_engine_mixed_buckets_separate_queues():

@@ -156,12 +156,13 @@ def test_warmup_prebuilds_profile_executables():
     srv = _server(sweeps=2, obs=obs, clock=obs.clock)
     profile = TrafficProfile.from_shapes(
         [("eigh", (6, 6), 3), ("eigh", (5, 5), 1), ("svd", (12, 6), 2)])
-    # (6,6) and (5,5) share the (8,8) bucket -> two distinct executables
-    assert len(srv.warmup_keys(profile)) == 2
+    # (6,6) and (5,5) share the (8,8) bucket -> two buckets, each with
+    # an executable per rung of max_batch 2 (batches 1 and 2)
+    assert len(srv.warmup_keys(profile)) == 4
     doc = srv.warmup(profile)
-    assert doc["executables"] == 2 and doc["compile"] == 2
+    assert doc["executables"] == 4 and doc["compile"] == 4
     again = srv.warmup(profile)
-    assert again["memory"] == 2 and again["compile"] == 0
+    assert again["memory"] == 4 and again["compile"] == 0
     # warm traffic is all cache hits from the first flush
     srv.solve_many([_sym(6), _sym(5)], op="eigh")
     assert srv.stats.summary()["cache_hit_rate"] == 1.0
@@ -183,15 +184,16 @@ def test_warmup_keys_ordered_by_descending_traffic_weight():
         ("pca", (12, 6), 4),
     ])
     keys = srv.warmup_keys(profile)
-    assert [(k[0], k[1]) for k in keys] == [
-        ("eigh", (8, 8)),          # weight 7
-        ("pca", (16, 8)),          # weight 4
-        ("svd", (16, 8)),          # weight 1
+    # each bucket's rungs (batches 1 and 2) come together, ascending
+    assert [(k[0], k[1], k[2]) for k in keys] == [
+        ("eigh", (8, 8), 1), ("eigh", (8, 8), 2),    # weight 7
+        ("pca", (16, 8), 1), ("pca", (16, 8), 2),    # weight 4
+        ("svd", (16, 8), 1), ("svd", (16, 8), 2),    # weight 1
     ]
     # bare (op, shape) rows (no counts) keep working: weight 1 each,
     # insertion order preserved
     bare = srv.warmup_keys([("svd", (12, 6)), ("eigh", (6, 6))])
-    assert [k[0] for k in bare] == ["svd", "eigh"]
+    assert [k[0] for k in bare] == ["svd", "svd", "eigh", "eigh"]
 
 
 def test_apply_plan_prewarms_incoming_executables():
@@ -293,16 +295,18 @@ def test_fingerprint_keys_device_kind(tmp_path):
                    jax.devices()[0].device_kind)
     mats = [_sym(6)]
     expect = _server(tmp_path).solve_many(mats, op="eigh")
-    path = next(iter(tmp_path.glob("*.jexec")))
-    record = pickle.loads(path.read_bytes())
-    assert tuple(record["env"]) == env
-    record["env"] = env[:2] + ("TPU v4",)
-    path.write_bytes(pickle.dumps(record))
+    paths = sorted(tmp_path.glob("*.jexec"))
+    assert len(paths) == 2             # the bucket's rungs, batches 1 and 2
+    for path in paths:
+        record = pickle.loads(path.read_bytes())
+        assert tuple(record["env"]) == env
+        record["env"] = env[:2] + ("TPU v4",)
+        path.write_bytes(pickle.dumps(record))
 
     srv = _server(tmp_path)
     _assert_results_equal(expect, srv.solve_many(mats, op="eigh"))
     disk = srv.cache_summary()["disk"]
-    assert disk["hits"] == 0 and disk["errors"] == 1
+    assert disk["hits"] == 0 and disk["errors"] == 2
 
 
 _WARMER = """\
@@ -313,7 +317,7 @@ srv = PCAServer(PCAConfig(T=8, S=2, sweeps=2), policy=BucketPolicy(T=8),
                 max_delay_s=10.0, cache_dir=sys.argv[1])
 doc = srv.warmup(TrafficProfile.from_shapes(
     [("eigh", (6, 6), 1), ("svd", (12, 6), 1)]))
-assert doc["executables"] == 2, doc
+assert doc["executables"] == 4, doc
 print("warmed")
 """
 
@@ -337,7 +341,7 @@ def test_concurrent_warmers_share_one_cache_dir(tmp_path):
                     max_delay_s=10.0, cache_dir=str(tmp_path))
     doc = srv.warmup(TrafficProfile.from_shapes(
         [("eigh", (6, 6), 1), ("svd", (12, 6), 1)]))
-    assert doc["compile"] == 0 and doc["disk"] == doc["executables"] == 2
+    assert doc["compile"] == 0 and doc["disk"] == doc["executables"] == 4
     assert srv.cache_summary()["disk"]["errors"] == 0
 
 

@@ -260,7 +260,62 @@ def test_cache_isolation_across_mesh_shapes_and_partial_flush():
             "batch_sizes": batch_sizes}))
     """)
     assert out["ok"] == [True, True, True]
-    assert out["n_execs"] == 3          # one executable per mesh shape
+    # one executable per rung per mesh shape: max_batch 8's rungs are
+    # 1, 2, 4, 8 locally, 2, 4, 8 on the 2-wide mesh, 4, 8 on the 4-wide
+    assert out["n_execs"] == 4 + 3 + 2
     assert out["ok_partial"]
     assert out["compiled_batches"] == [8]   # 3 requests padded up to 8
     assert out["batch_sizes"] == [3]        # telemetry reports live batch
+
+
+def test_mesh_rungs_round_to_the_data_axis_and_serve_without_compiling():
+    """On a 4-wide data axis every rung rounds up to a multiple of 4 and
+    none repeats: max_batch 8 has rungs 4 and 8.  A bucket's first flush
+    builds both, and flushes of 1 to 8 live requests then compile nothing
+    (the slab goes straight onto the data axis, so the ahead-of-time
+    executables never reshard) and answer correctly."""
+    out = _run("""
+        import jax.monitoring as mon
+        from repro.core import PCAConfig
+        from repro.serving import (BucketPolicy, MeshExecutor, PCAServer,
+                                   host_mesh)
+        from repro.serving.batching import batch_rungs
+        ex = MeshExecutor(mesh=host_mesh(4))
+        rungs = {mb: list(batch_rungs(mb, ex.round_batch))
+                 for mb in (1, 2, 4, 6, 8)}
+        compiles = []
+        mon.register_event_duration_secs_listener(
+            lambda e, d, **kw: compiles.append(e)
+            if e == "/jax/core/compile/backend_compile_duration" else None)
+        srv = PCAServer(PCAConfig(T=8, S=8, sweeps=14),
+                        policy=BucketPolicy(T=8), max_batch=8,
+                        max_delay_s=1e9, executor=ex)
+        rng = np.random.default_rng(3)
+        def sym():
+            a = rng.standard_normal((6, 6)).astype(np.float32)
+            return (a + a.T) / 2
+        srv.submit(sym()).wait()
+        cached = sorted(k[2] for k in srv._cache)
+        srv.stats.reset()
+        n_warm = len(compiles)
+        ok = True
+        for b in (1, 3, 5, 8, 2):
+            mats = [sym() for _ in range(b)]
+            tickets = [srv.submit(m) for m in mats]
+            srv.drain()
+            ok &= all(np.allclose(t.result().eigenvalues,
+                                  np.linalg.eigh(m)[0][::-1],
+                                  rtol=1e-3, atol=1e-3)
+                      for t, m in zip(tickets, mats))
+        print(json.dumps({
+            "rungs": rungs, "cached": cached, "ok": bool(ok),
+            "padded": [f.padded_batch for f in srv.stats.flush_records],
+            "misses": srv.stats.cache_misses,
+            "compiles": len(compiles) - n_warm}))
+    """, device_count=4)
+    assert out["rungs"] == {"1": [4], "2": [4], "4": [4], "6": [4, 8],
+                            "8": [4, 8]}
+    assert out["cached"] == [4, 8]
+    assert out["padded"] == [4, 4, 8, 8, 4]
+    assert out["ok"]
+    assert out["misses"] == 0 and out["compiles"] == 0

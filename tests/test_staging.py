@@ -28,6 +28,11 @@ def _mats(rng, shapes):
     return [rng.standard_normal(s).astype(np.float32) for s in shapes]
 
 
+# the rung (padded batch) a flush of b live requests runs at: the smallest
+# of max_batch 4's rungs 1, 2 and 4 that holds b
+RUNG = {1: 1, 2: 2, 3: 4, 4: 4}
+
+
 # flush sequences of one (1808, 64) bucket at bp=4: rows that shrink and
 # grow, a full flush then a partial one (slots that held data become
 # filler), and an exact-fit single request
@@ -53,6 +58,31 @@ def test_pool_slab_equals_stacked_and_padded(name):
         want = reference(mats, bucket, bp)[0]
         assert slab.array.dtype == want.dtype
         assert np.array_equal(slab.array, want)
+        pool.release(slab, max_free=1)
+
+
+# flushes of 4, 3, 1, 3, 2 and 4 live requests: a slot that held data
+# becomes filler at rung 4, and slots past a smaller rung keep stale data
+RUNG_SEQUENCE = [[(1808, 64), (1797, 64), (1800, 50), (1799, 63)],
+                 [(1797, 64), (1800, 64), (1808, 30)], [(1801, 64)],
+                 [(1797, 40)] * 3, [(1808, 64), (1799, 20)],
+                 [(1800, 64)] * 4]
+
+
+@pytest.mark.parametrize("start", [0, 1, 2])
+def test_pool_batch_at_each_rung_equals_stacked_and_padded(start):
+    """Each flush at its rung in the leading slots of one four-slot slab:
+    the batch the device is given equals the reference at the rung,
+    whatever the slots past it still hold."""
+    rng = np.random.default_rng(start)
+    pool, bucket = StagingPool(), (1808, 64)
+    for i, shapes in enumerate(RUNG_SEQUENCE[start:]):
+        mats = _mats(rng, shapes)
+        bp = RUNG[len(mats)]
+        slab, reused = pool.take(mats, bucket, bp, slots=4)
+        assert reused == (i > 0) and slab.array.shape[0] == 4
+        assert np.shares_memory(slab.batch, slab.array)
+        assert np.array_equal(slab.batch, reference(mats, bucket, bp)[0])
         pool.release(slab, max_free=1)
 
 
@@ -94,7 +124,7 @@ class RecordingExecutor(LocalExecutor):
 
     def submit(self, fn, batch, n_active, **kw):
         busy = [f.slab.array for f in self.server._inflight]
-        assert not any(batch is a for a in busy)
+        assert not any(np.shares_memory(batch, a) for a in busy)
         self.seen.append((batch.copy(), n_active.copy()))
         return super().submit(fn, batch, n_active, **kw)
 
@@ -118,7 +148,8 @@ def _traffic(seed=7, n=34):
 
 def _serve(srv, mats):
     """Bursts of 4, 4, 4, 2, 4, 1, ...: full flushes, three in a row, and
-    partial ones forced by ``drain``, so filler slots follow live ones."""
+    partial ones forced by ``drain``, at rungs 2, 1 and (three live) 4,
+    so filler slots follow live ones."""
     tickets, i = [], 0
     for b in (4, 4, 4, 2, 4, 1, 4, 4, 4, 3):
         tickets += [srv.submit(m, op="pca") for m in mats[i:i + b]]
@@ -136,7 +167,7 @@ def test_engine_hands_the_executor_the_stacked_slab():
     at = np.cumsum([0] + sizes)
     assert len(srv.executor.seen) == len(sizes) > 4
     for (batch, n_active), lo, hi in zip(srv.executor.seen, at, at[1:]):
-        want_b, want_n = reference(mats[lo:hi], (24, 8), 4)
+        want_b, want_n = reference(mats[lo:hi], (24, 8), RUNG[hi - lo])
         assert np.array_equal(batch, want_b)
         assert np.array_equal(n_active, want_n)
         assert n_active.dtype == np.int32
